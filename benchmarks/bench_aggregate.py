@@ -225,7 +225,7 @@ def bench_collective(
     """The shard_map setting over the host devices (m := device count),
     per registered communication topology (``repro.comm``) and wire
     precision (``repro.comm.quantize``)."""
-    from repro.compat import make_mesh, shard_map
+    from repro.launch.mesh import make_mesh
     from repro.core.distributed import procrustes_average_collective
     from jax.sharding import PartitionSpec as P
 
@@ -285,7 +285,7 @@ def bench_collective(
                                 return out[None]
 
                             fn = jax.jit(
-                                shard_map(
+                                jax.shard_map(
                                     shard_fn,
                                     mesh=hier_mesh if hier else mesh,
                                     in_specs=P(

@@ -100,10 +100,10 @@ def comm_measured(*, check: bool = False, bits=(32, 8)) -> bool:
 import os, json
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={m}"
 import jax, jax.numpy as jnp
-from repro import compat
 from repro.core.distributed import distributed_pca
 from repro.launch.hlo_analysis import collective_bytes
-mesh = compat.make_mesh(({m},), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh(({m},), ("data",))
 d, r, n = {d}, {r}, {n}
 samples = jax.ShapeDtypeStruct(({m} * n, d), jnp.float32)
 for topology in {list(flat_topos)!r}:
@@ -130,6 +130,7 @@ for cb in {list(bits)!r}:
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src"
     )
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"  # virtual devices; never the chip
     out = subprocess.run(
         [sys.executable, "-c", code], env=env, capture_output=True, text=True,
         timeout=900,
@@ -240,12 +241,12 @@ def hier_measured(*, check: bool = False, bits=(32, 8)) -> bool:
 import os, json
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={m}"
 import jax, jax.numpy as jnp
-from repro import compat
 from repro.comm import Membership
 from repro.core.distributed import distributed_pca
 from repro.launch.hlo_analysis import collective_bytes
-hier_mesh = compat.make_mesh(({pods}, {m // pods}), ("pod", "data"))
-flat_mesh = compat.make_mesh(({m},), ("data",))
+from repro.launch.mesh import make_mesh
+hier_mesh = make_mesh(({pods}, {m // pods}), ("pod", "data"))
+flat_mesh = make_mesh(({m},), ("data",))
 def measure(mesh, topology, n_iter, cb, mem=None, d={d}, r={r}):
     samples = jax.ShapeDtypeStruct(({m} * {n}, d), jnp.float32)
     fn = jax.jit(lambda s: distributed_pca(
@@ -278,6 +279,7 @@ for kind, mesh, topo in (("hier-big", hier_mesh, "hier"),
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src"
     )
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"  # virtual devices; never the chip
     out = subprocess.run(
         [sys.executable, "-c", code], env=env, capture_output=True, text=True,
         timeout=900,

@@ -14,7 +14,7 @@ resolved topology; ``benchmarks/bench_comm.py`` and
 ``repro.launch.dryrun`` consume the cost model instead of hand-writing
 the formulas.
 
-This package deliberately depends only on ``jax`` and ``repro.compat`` at
+This package deliberately depends only on ``jax`` at
 import time (core/kernels imports are function-level), so it sits below
 ``repro.core`` in the layering.
 """
@@ -42,7 +42,6 @@ from repro.comm.topology import (  # noqa: F401
     TOPOLOGIES,
     TOPOLOGY_CHOICES,
     CommCost,
-    axis_size,
     broadcast_from,
     comm_cost,
     fan_projector_words,

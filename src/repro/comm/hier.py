@@ -59,7 +59,7 @@ from repro.comm.quantize import (
     wire_broadcast,
 )
 from repro.comm.ring import DEFAULT_RING_CHUNK, chunk_spans
-from repro.comm.topology import DATA_AXIS, POD_AXIS, axis_size, broadcast_from
+from repro.comm.topology import DATA_AXIS, POD_AXIS, broadcast_from
 
 __all__ = ["hier_rounds"]
 
@@ -178,8 +178,8 @@ def hier_rounds(
     resolve_polar(polar)
     resolve_orth(orth)
     codec = get_codec(comm_bits)
-    p = axis_size(pod_axis)
-    local = axis_size(local_axis)
+    p = jax.lax.axis_size(pod_axis)
+    local = jax.lax.axis_size(local_axis)
     mem = resolve_membership(membership, p * local)
     pmem = pod_membership(mem, p)
     base_key = (

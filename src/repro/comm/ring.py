@@ -169,14 +169,14 @@ def ring_rounds(
     tiers are replicated exactly as far, since every shard decodes the
     same m payloads).
     """
-    from repro.comm.topology import axis_size, broadcast_from
+    from repro.comm.topology import broadcast_from
     from repro.core.orthonorm import orthonormalize, resolve_orth
     from repro.core.procrustes import resolve_polar
 
     resolve_polar(polar)
     resolve_orth(orth)
     codec = get_codec(comm_bits)
-    m = axis_size(axis_name)
+    m = jax.lax.axis_size(axis_name)
     mem = resolve_membership(membership, m)
     base_key = shard_key(axis_name, _RING_SALT) if codec.stochastic else None
     if ref is None:
@@ -255,9 +255,7 @@ def fused_ring_rounds(
     from repro.kernels import ops as kops
 
     codec = get_codec(comm_bits)
-    from repro.comm.topology import axis_size
-
-    m = axis_size(axis_name)
+    m = jax.lax.axis_size(axis_name)
     mem = resolve_membership(membership, m)
     base_key = shard_key(axis_name, _RING_SALT) if codec.stochastic else None
     if ref is None:
@@ -347,7 +345,14 @@ def _ring_round(
         buf_c = [to_wire(data[s:e]) for s, e in spans]
     else:
         scale = None
-        buf_c = [v_local[s:e].astype(jnp.float32) for s, e in spans]
+        # The exact-wire payload is the same every round, and XLA would
+        # merge later rounds' hops into the first round's (CSE), holding
+        # all m'-1 received bases alive across rounds instead of the
+        # O(d·r) working set above.  Adding 0·ref[0, 0] (exact for a
+        # finite reference; XLA does not fold a float product with zero)
+        # makes each round's payload a value of its own.
+        tie = 0.0 * ref[:1, :1].astype(jnp.float32)
+        buf_c = [v_local[s:e].astype(jnp.float32) + tie for s, e in spans]
 
     def dec(chunks, sc):
         if not codec.lossy:

@@ -67,7 +67,6 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size as _compat_axis_size
 from repro.comm.membership import Membership, pod_membership, resolve_membership
 from repro.comm.quantize import message_bits, resolve_comm_bits
 
@@ -78,7 +77,6 @@ __all__ = [
     "TOPOLOGIES",
     "TOPOLOGY_CHOICES",
     "resolve_topology",
-    "axis_size",
     "broadcast_from",
     "CommCost",
     "comm_cost",
@@ -126,16 +124,6 @@ def resolve_topology(topology: str, backend: str = "xla") -> str:
             f"topology must be one of {TOPOLOGY_CHOICES}, got {topology!r}"
         )
     return topology
-
-
-def axis_size(axis_name: str) -> int:
-    """Static size of a named mesh axis (no collective on the wire).
-
-    Resolved through ``repro.compat.axis_size``: ``jax.lax.axis_size``
-    where it exists, the statically-folded ``psum(1, axis)`` on 0.4.x, and
-    a genuine ``psum(ones)`` all-reduce only on JAX too old for either.
-    """
-    return _compat_axis_size(axis_name)
 
 
 def broadcast_from(x: jax.Array, axis_name: str, src: int = 0) -> jax.Array:

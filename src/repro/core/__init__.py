@@ -33,7 +33,6 @@ from repro.core.eigenspace import (  # noqa: F401
 )
 from repro.core.covariance import empirical_covariance  # noqa: F401
 from repro.core.distributed import (  # noqa: F401
-    axis_size,
     broadcast_from,
     distributed_pca,
     distributed_pca_from_covs,

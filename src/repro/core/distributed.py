@@ -42,8 +42,7 @@ bit-keyed tolerances in ``repro.comm.PARITY_TOL``.
 
 All collective functions here are written to be called *inside*
 ``shard_map`` with a named mesh axis; the ``distributed_pca`` driver wraps
-them for end-to-end use.  The shard_map / mesh spellings resolve through
-``repro.compat`` so the module runs on both old and new JAX.
+them for end-to-end use.
 """
 
 from __future__ import annotations
@@ -55,7 +54,6 @@ from jax.sharding import PartitionSpec as P
 from repro.comm import (
     DATA_AXIS,
     POD_AXIS,
-    axis_size,
     broadcast_from,
     fused_ring_rounds,
     get_codec,
@@ -67,15 +65,14 @@ from repro.comm import (
 )
 from repro.comm.membership import Membership, resolve_membership
 from repro.comm.quantize import from_wire, shard_key, to_wire
-from repro.compat import shard_map
 from repro.core import procrustes
 from repro.core.covariance import empirical_covariance
 from repro.core.eigenspace import refinement_rounds
 from repro.core.orthonorm import orthonormalize, resolve_orth
+from repro.core.precision import highest_precision
 from repro.core.subspace import local_eigenbasis
 
 __all__ = [
-    "axis_size",
     "broadcast_from",
     "procrustes_average_collective",
     "sign_average_collective",
@@ -100,6 +97,7 @@ def _align_local(
     return procrustes.align(v, ref, polar=polar)
 
 
+@highest_precision
 def procrustes_average_collective(
     v_local: jax.Array,
     *,
@@ -174,7 +172,7 @@ def procrustes_average_collective(
     from repro.plan.planner import resolve_plan
 
     d, r = v_local.shape
-    pods = axis_size(pod_axis) if pod_axis is not None else None
+    pods = jax.lax.axis_size(pod_axis) if pod_axis is not None else None
     if topology == "hier" and pod_axis is None:
         # The post-resolution coupling check below also covers this, but
         # resolve_plan would name the missing ``pods=`` first; the actual
@@ -184,7 +182,7 @@ def procrustes_average_collective(
             "schedule needs the 2-D (pod, local) mesh axes "
             "(got pod_axis=None)"
         )
-    m_total = (pods or 1) * axis_size(axis_name)
+    m_total = (pods or 1) * jax.lax.axis_size(axis_name)
     mem = resolve_membership(membership, m_total)
     pl = resolve_plan(
         plan, m=mem.m, d=d, r=r, n_iter=n_iter,
@@ -317,7 +315,7 @@ def procrustes_average_collective(
 
 def sign_average_collective(v_local: jax.Array, *, axis_name: str) -> jax.Array:
     """Rank-1 sign-fixing (Garber et al.) across a mesh axis."""
-    m = axis_size(axis_name)
+    m = jax.lax.axis_size(axis_name)
     ref = broadcast_from(v_local, axis_name, src=0)
     fixed = procrustes.sign_fix(v_local, ref)
     vbar = jax.lax.psum(fixed, axis_name) / m
@@ -433,7 +431,7 @@ def distributed_pca(
 
     spec_in = P(axes, *(None,) * (samples.ndim - 1))
     fn = jax.jit(
-        shard_map(
+        jax.shard_map(
             shard_fn, mesh=mesh, in_specs=spec_in,
             out_specs=P(axes, None, None), check_vma=False
         )
@@ -500,7 +498,7 @@ def distributed_pca_from_covs(
 
     if ref is None:
         fn = jax.jit(
-            shard_map(
+            jax.shard_map(
                 lambda c: shard_fn(c, None),
                 mesh=mesh,
                 in_specs=P(axes, None, None),
@@ -510,7 +508,7 @@ def distributed_pca_from_covs(
         )
         return fn(covs)[0]
     fn = jax.jit(
-        shard_map(
+        jax.shard_map(
             shard_fn,
             mesh=mesh,
             in_specs=(P(axes, None, None), P(None, None)),

@@ -80,6 +80,7 @@ from repro.core.orthonorm import (
     qr_orthonormalize,
     resolve_orth,
 )
+from repro.core.precision import highest_precision
 from repro.core.subspace import local_eigenbasis
 
 __all__ = [
@@ -151,6 +152,7 @@ def _rounds_xla(
     return out
 
 
+@highest_precision
 def refinement_rounds(
     vs: jax.Array,
     ref: jax.Array | None = None,

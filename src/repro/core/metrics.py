@@ -5,6 +5,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core.precision import highest_precision
+
 __all__ = [
     "dist_2",
     "dist_f",
@@ -15,8 +17,13 @@ __all__ = [
 ]
 
 
+@highest_precision
 def _gram_singulars(u: jax.Array, v: jax.Array) -> jax.Array:
-    """Singular values of u^T v (cosines of principal angles), clipped to [0,1]."""
+    """Singular values of u^T v (cosines of principal angles), clipped to [0,1].
+
+    The Gram runs at HIGHEST precision (``repro.core.precision``): a
+    distance should not carry the rounding of a TPU's default product.
+    """
     s = jnp.linalg.svd(u.T @ v, compute_uv=False)
     return jnp.clip(s, 0.0, 1.0)
 

@@ -16,6 +16,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.precision import highest_precision
+
 __all__ = ["top_r_eigh", "subspace_iteration", "local_eigenbasis"]
 
 
@@ -30,6 +32,7 @@ def top_r_eigh(x: jax.Array, r: int) -> Tuple[jax.Array, jax.Array]:
 
 
 @functools.partial(jax.jit, static_argnames=("r", "iters"))
+@highest_precision
 def subspace_iteration(
     x: jax.Array,
     r: int,
@@ -46,6 +49,7 @@ def subspace_iteration(
     fast.  A final Rayleigh–Ritz step returns an eigen-ordered basis.
 
     Returns (V, lam): V (d, r) orthonormal, lam (r,) Ritz values descending.
+    Every product runs at HIGHEST precision (``repro.core.precision``).
     """
     d = x.shape[0]
     if v0 is None:

@@ -30,12 +30,39 @@ __all__ = [
 ]
 
 
+_HAAR_PANEL = 128
+_HAAR_PANEL_MIN_D = 2048  # below this a d x d QR compiles fast enough
+
+
 def random_orthogonal(key: jax.Array, d: int, dtype=jnp.float32) -> jax.Array:
-    """Haar-ish random orthogonal matrix via QR of a Gaussian."""
+    """Haar random orthogonal matrix: the Q of a Gaussian's QR, with signs
+    fixed so that R's diagonal is positive (Mezzadri 2007)."""
     g = jax.random.normal(key, (d, d), dtype=dtype)
-    q, r = jnp.linalg.qr(g)
-    # Fix signs so the distribution is exactly Haar (Mezzadri 2007).
-    return q * jnp.sign(jnp.diagonal(r))[None, :]
+    if d < _HAAR_PANEL_MIN_D or d % _HAAR_PANEL:
+        q, r = jnp.linalg.qr(g)
+        return q * jnp.sign(jnp.diagonal(r))[None, :]
+    return _panel_qr_q(g)
+
+
+@jax.jit
+def _panel_qr_q(g: jax.Array) -> jax.Array:
+    """The same Q for a wide d, panel by panel: each 128-column panel is
+    projected off the columns already made, twice (block Gram–Schmidt
+    with reorthogonalization), then thin-QR'd with its signs fixed.  Only
+    matmuls and tall-skinny QRs: a d x d QR takes minutes to compile for a
+    TPU at d=8192, this takes seconds."""
+    d = g.shape[0]
+    hi = jax.lax.Precision.HIGHEST
+
+    def panel(k, q):
+        p = jax.lax.dynamic_slice_in_dim(g, k * _HAAR_PANEL, _HAAR_PANEL, 1)
+        for _ in range(2):  # q's columns not yet made are zero
+            p = p - jnp.dot(q, jnp.dot(q.T, p, precision=hi), precision=hi)
+        qp, rp = jnp.linalg.qr(p)
+        qp = qp * jnp.sign(jnp.diagonal(rp))[None, :]
+        return jax.lax.dynamic_update_slice_in_dim(q, qp, k * _HAAR_PANEL, 1)
+
+    return jax.lax.fori_loop(0, d // _HAAR_PANEL, panel, jnp.zeros_like(g))
 
 
 def spectrum_m1(

@@ -62,6 +62,7 @@ __all__ = [
     "align_average",
     "fused_round",
     "fused_ring_round",
+    "fused_ring_vmem_bytes",
 ]
 
 # Keep in sync with repro.core.procrustes.DEFAULT_NS_ITERS (not imported to
@@ -471,19 +472,20 @@ def fused_round(
 # ``fused_round`` above consumes an already-materialized (m, d, r) stack and
 # pays 4 streams of it per round (V̄ is recomputed from the Z stack in
 # phases 1-3).  The ring variant instead walks the m' hops directly: grid
-# step (i, c) lands hop i's chunk c in a double-buffered VMEM scratch slot
-# via a manual async copy (the ``emit_pipeline`` style — start chunk t+1's
-# DMA, compute on chunk t) while the MXU runs hop i's Gram and hop i-1's
-# apply.  The running V̄ is *fully VMEM-resident* for the whole round, so
-# each hop's basis is read from HBM exactly once and the CholeskyQR2 tail
-# re-streams V̄ from scratch memory, not from HBM: per-round traffic is
-# ~(1 + 2/m) basis-streams instead of 4 (DESIGN.md §3.3).
+# step (i, c) receives hop i's chunk c through the Pallas pipeline (the
+# next chunk's copy is in flight while the MXU runs hop i's Gram and hop
+# i-1's apply) and keeps it in a two-slot VMEM hop buffer until its apply
+# one hop later.  The running V̄ is *fully VMEM-resident* for the whole
+# round, so each hop's basis is read from HBM exactly once and the
+# CholeskyQR2 tail re-streams V̄ from scratch memory, not from HBM:
+# per-round traffic is ~(1 + 2/m) basis-streams instead of 4 (DESIGN.md
+# §3.3).  Off-TPU the wire payloads are pre-gathered by
+# ``repro.comm.ring.fused_ring_rounds`` and run under the Pallas
+# interpreter.
 #
-# The circulating buffer is HBM-staged (``memory_space=ANY``): off-TPU the
-# wire payloads are pre-gathered by ``repro.comm.ring.fused_ring_rounds``
-# and the in-kernel copies double-buffer them through VMEM under the Pallas
-# interpreter; on real ICI the same schedule maps to remote DMA
-# (``fused_ring_round_remote`` below, the compiled-TPU lane).
+# Every resident buffer is laid out (…, nc, chunk, r) and indexed only on
+# its leading dims: Mosaic refuses a slice of the lane dim narrower than
+# 128, which any r < 128 would otherwise need.
 
 
 # Wire dtypes the in-kernel decoder understands, keyed by comm_bits (kept
@@ -494,83 +496,75 @@ _WIRE_BITS = {jnp.dtype(jnp.float32): 32,
               jnp.dtype(jnp.int8): 8}
 
 
+def fused_ring_vmem_bytes(d: int, r: int, wire_bits: int) -> float:
+    """Resident working set of ``fused_ring_round`` as the planner prices
+    it: three wire-width (d, r) hop buffers (two hop slots plus the
+    pipeline's in-flight chunks) and the f32 V̄ / ref / out — the
+    (3·wire + 12)·d·r bytes checked against the VMEM envelope."""
+    return d * r * (3 * wire_bits / 8.0 + 3 * 4.0)
+
+
+def _mosaic_vmem_limit(m: int, dp: int, chunk: int, r: int, wire: int) -> int:
+    """Scoped-VMEM limit for the compiled ring kernel: its buffers with the
+    lane dim padded to 128 (as Mosaic lays them out) and the pipeline's
+    double-buffering of the resident blocks, plus headroom for in-kernel
+    temporaries."""
+    lanes = -(-r // 128) * 128
+    f32 = dp * lanes * 4
+    footprint = (
+        2 * chunk * lanes * wire      # pipelined hop chunks in flight
+        + 2 * dp * lanes * wire       # two hop slots
+        + 5 * f32                     # V̄ + double-buffered ref and out
+        + 2 * m * 8 * lanes * 4       # double-buffered (m, 1, r) scales
+    )
+    return int(footprint + 16 * 2**20)
+
+
 def _fused_ring_round_kernel(
-    vs_hbm, ref, scales, out, hopbuf, vbar, g, z, sem, *,
-    m: int, nc: int, chunk: int, d: int, ns_iters: int,
-    pivot_c: float, shift_c: float, bits: int,
+    x_in, ref, scales, out, hop, vbar, g, z, *,
+    m: int, nc: int, ns_iters: int, pivot_c: float, shift_c: float,
+    bits: int,
 ):
     """One ring-scheduled Algorithm-1 round; see ``fused_ring_round``.
 
-    Grid (m+1, nc), hop-major / chunk-minor, step t = i*nc + c:
+    Grid (m+1, nc), hop-major / chunk-minor:
 
-      DMA     wait hop i's chunk c (started at step t-1), start step t+1's
-              copy into slot (hop t+1) % 3 — three hop slots so the copy in
-              flight, hop i's Gram reads and hop i-1's apply reads never
-              share a buffer, even at nc == 1.
-      Gram    g += dec(hop i chunk c)^T @ ref[chunk c]      (i < m)
-      apply   V̄[chunk c] += dec(hop i-1 chunk c) @ z        (i >= 1)
+      Gram    g += dec(hop i chunk c)^T @ ref[c]; keep the chunk   (i < m)
+      apply   V̄[c] += dec(hop i-1 chunk c) @ z                     (i >= 1)
       polar   z = NS(g) at c == nc-1, AFTER the apply consumed the old z
       tail    i == m, c == nc-1: V̄ /= m' and both CholeskyQR2 passes run
               on the resident V̄ — S2 is the Gram of the *measured* Q1, so
               the CholeskyQR2 bound is preserved.
 
-    Ragged d: chunks are fixed-length with clamped starts
-    ``s = min(c*chunk, d-chunk)`` (the last window slides back over rows
-    the previous chunk already handled); a per-chunk freshness mask zeroes
-    the re-read rows so Gram/apply add exact zeros for them.  Masking is
-    per-chunk — there is no per-launch padding, any d >= 1 works.
+    The wrapper pads d to a whole number of chunks; zero rows add exact
+    zeros to every Gram and apply, and leave V̄'s Gram unchanged.
     """
     i = pl.program_id(0)
     c = pl.program_id(1)
-    t = i * nc + c
-    total = m * nc
 
-    def copy_for(tt):
-        hop = tt // nc
-        ck = tt % nc
-        sx = jnp.minimum(ck * chunk, d - chunk)
-        return pltpu.make_async_copy(
-            vs_hbm.at[pl.ds(hop, 1), pl.ds(sx, chunk), :],
-            hopbuf.at[pl.ds(hop % 3, 1), pl.ds(sx, chunk), :],
-            sem.at[tt % 2],
-        )
-
-    @pl.when(t == 0)
-    def _prologue():
-        vbar[...] = jnp.zeros_like(vbar)
-        copy_for(t).start()
-
-    s = jnp.minimum(c * chunk, d - chunk)
-    rows = s + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
-    fresh = rows >= c * chunk  # rows re-read from the previous chunk -> 0
-
-    def dec(hop, slot):
-        blk = hopbuf[pl.ds(slot, 1), pl.ds(s, chunk), :][0]
+    def dec(blk, h):
         x = blk.astype(jnp.float32)
         if bits == 8:
-            x = x * scales[pl.ds(hop, 1), :]
-        return jnp.where(fresh, x, 0.0)
+            x = x * scales[h]
+        return x
+
+    @pl.when((i == 0) & (c == 0))
+    def _init():
+        vbar[...] = jnp.zeros_like(vbar)
 
     @pl.when(i < m)
     def _hop_in():
-        copy_for(t).wait()
-
-        @pl.when(t + 1 < total)
-        def _prefetch():
-            copy_for(t + 1).start()
-
-        x = dec(i, i % 3)
+        blk = x_in[0]
+        hop[i % 2, c] = blk  # applied at hop i+1, once z is known
         contrib = jnp.dot(
-            x.T, ref[pl.ds(s, chunk), :], preferred_element_type=jnp.float32
+            dec(blk, i).T, ref[c], preferred_element_type=jnp.float32
         )
         g[...] = jnp.where(c == 0, contrib, g[...] + contrib)
 
     @pl.when(i >= 1)
     def _apply_prev():
-        x = dec(i - 1, (i - 1) % 3)
-        vbar[pl.ds(s, chunk), :] += jnp.dot(
-            x, z[...], preferred_element_type=jnp.float32
-        )
+        x = dec(hop[(i - 1) % 2, c], i - 1)
+        vbar[c] += jnp.dot(x, z[...], preferred_element_type=jnp.float32)
 
     @pl.when((i < m) & (c == nc - 1))
     def _polar():
@@ -578,13 +572,27 @@ def _fused_ring_round_kernel(
 
     @pl.when((i == m) & (c == nc - 1))
     def _tail():
-        vb = vbar[...] / m
-        s1 = jnp.dot(vb.T, vb, preferred_element_type=jnp.float32)
-        w1 = _cholqr_inverse_factor(s1, pivot_c=pivot_c, shift_c=shift_c)
-        q1 = jnp.dot(vb, w1, preferred_element_type=jnp.float32)
-        s2 = jnp.dot(q1.T, q1, preferred_element_type=jnp.float32)
-        w2 = _cholqr_inverse_factor(s2, pivot_c=pivot_c, shift_c=shift_c)
-        out[...] = jnp.dot(q1, w2, preferred_element_type=jnp.float32)
+        def gram_of(rows):
+            def body(k, acc):
+                q = rows(k)
+                return acc + jnp.dot(q.T, q, preferred_element_type=jnp.float32)
+
+            return jax.lax.fori_loop(0, nc, body, jnp.zeros_like(g[...]))
+
+        vb = lambda k: vbar[k] / m
+        w1 = _cholqr_inverse_factor(
+            gram_of(vb), pivot_c=pivot_c, shift_c=shift_c
+        )
+        q1 = lambda k: jnp.dot(vb(k), w1, preferred_element_type=jnp.float32)
+        w2 = _cholqr_inverse_factor(
+            gram_of(q1), pivot_c=pivot_c, shift_c=shift_c
+        )
+
+        def emit(k, carry):
+            out[k] = jnp.dot(q1(k), w2, preferred_element_type=jnp.float32)
+            return carry
+
+        jax.lax.fori_loop(0, nc, emit, 0)
 
 
 @functools.partial(
@@ -604,26 +612,25 @@ def fused_ring_round(
     Args:
       vs: (m', d, r) stack of per-shard wire payloads in **wire dtype**
         (f32 / bf16 / int8, per ``repro.comm.quantize``), in canonical
-        survivor order — hop h of the ring is row h.  The stack lives in
-        HBM (``memory_space=ANY``); the kernel's own async copies stream it
-        through triple-slotted VMEM scratch, one chunk ahead of the MXU.
+        survivor order — hop h of the ring is row h.  The stack stays in
+        HBM; the Pallas pipeline streams it one chunk ahead of the MXU.
       ref: (d, r) reference; accumulated against at f32.
       scales: (m', r) f32 per-column scales for the int8 tier (required
         iff ``vs.dtype == int8``).
       ring_chunk: rows per hop chunk — the DMA/compute overlap granularity,
         shared with the jnp schedule via ``repro.comm.ring.chunk_spans``
-        (need not divide d; see the kernel docstring for the ragged rule).
+        (need not divide d: the stack is zero-padded to whole chunks).
       ns_iters / interpret: as in ``fused_round``.
 
     Returns the (d, r) **f32** orthonormal round output — f32 so round k's
     output feeds round k+1's ``ref`` operand with no XLA cast (or any
     other op) between launches.
 
-    VMEM budget: the hop slots (3 x d x r at wire width) plus the resident
-    V̄/ref/out tiles (3 x d x r f32) — ~4.7 MiB at (d=4096, r=64, f32
-    wire), comfortably inside the 16 MiB envelope; the planner's
-    feasibility rule (``repro.plan.planner``) prices exactly this working
-    set and rejects the cell when it would not fit.
+    VMEM: ``fused_ring_vmem_bytes`` — ~12 MiB at (d=8192, r=64, f32
+    wire).  Compiled, a working set over the planner's envelope
+    (``repro.plan.roofline.TPU_V5E.vmem_cap_bytes``) raises ``ValueError``
+    before Mosaic sees the kernel; the planner marks the same cell
+    infeasible.
     """
     from repro.comm.ring import DEFAULT_RING_CHUNK, chunk_spans
 
@@ -639,164 +646,65 @@ def fused_ring_round(
             "scales must be passed iff the stack is int8 "
             f"(dtype={vs.dtype}, scales={'set' if scales is not None else None})"
         )
+    if not interpret:
+        from repro.plan.roofline import TPU_V5E
+
+        need = fused_ring_vmem_bytes(d, r, bits)
+        if need > TPU_V5E.vmem_cap_bytes:
+            raise ValueError(
+                f"fused_ring_round at d={d}, r={r} with a {bits}-bit wire "
+                f"keeps {need / 2**20:.1f} MiB resident, over the "
+                f"{TPU_V5E.vmem_cap_bytes / 2**20:.0f} MiB VMEM envelope; "
+                "use another topology or a narrower wire"
+            )
     chunk = DEFAULT_RING_CHUNK if ring_chunk is None else ring_chunk
-    spans = chunk_spans(d, chunk)
-    nc = len(spans)
     chunk = max(1, min(chunk, d))
+    nc = len(chunk_spans(d, chunk))
+    dp = nc * chunk
+    if dp != d:
+        vs = jnp.pad(vs, ((0, 0), (0, dp - d), (0, 0)))
+        ref = jnp.pad(ref, ((0, dp - d), (0, 0)))
     eps = float(jnp.finfo(jnp.float32).eps)
     # Keep in sync with repro.core.orthonorm.cholqr_guard_coeffs.
     pivot_c, shift_c = r * eps, 11.0 * (d + r + 1) * eps
     if scales is None:
         scales = jnp.ones((m, r), jnp.float32)  # static no-op (bits != 8)
-    return pl.pallas_call(
+    resident = lambda i, c: (0, 0, 0)
+    out = pl.pallas_call(
         functools.partial(
-            _fused_ring_round_kernel, m=m, nc=nc, chunk=chunk, d=d,
-            ns_iters=ns_iters, pivot_c=pivot_c, shift_c=shift_c, bits=bits,
+            _fused_ring_round_kernel, m=m, nc=nc, ns_iters=ns_iters,
+            pivot_c=pivot_c, shift_c=shift_c, bits=bits,
         ),
         grid=(m + 1, nc),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),       # vs stays HBM-staged
-            pl.BlockSpec((d, r), lambda i, c: (0, 0)),  # ref resident
-            pl.BlockSpec((m, r), lambda i, c: (0, 0)),  # scales resident
+            # Hop i's chunk c; the tail step re-uses the last block, so
+            # nothing is fetched twice.
+            pl.BlockSpec(
+                (1, chunk, r),
+                lambda i, c: (
+                    jnp.minimum(i, m - 1), jnp.where(i < m, c, nc - 1), 0
+                ),
+            ),
+            pl.BlockSpec((nc, chunk, r), resident),
+            pl.BlockSpec((m, 1, r), resident),
         ],
-        out_specs=pl.BlockSpec((d, r), lambda i, c: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((d, r), jnp.float32),
+        out_specs=pl.BlockSpec((nc, chunk, r), resident),
+        out_shape=jax.ShapeDtypeStruct((nc, chunk, r), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((3, d, r), vs.dtype),    # hop slots (wire dtype)
-            pltpu.VMEM((d, r), jnp.float32),    # resident running V̄
-            pltpu.VMEM((r, r), jnp.float32),    # Gram accumulator
-            pltpu.VMEM((r, r), jnp.float32),    # polar factor Z of hop i-1
-            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((2, nc, chunk, r), vs.dtype),  # hop slots (wire)
+            pltpu.VMEM((nc, chunk, r), jnp.float32),  # resident running V̄
+            pltpu.VMEM((r, r), jnp.float32),          # Gram accumulator
+            pltpu.VMEM((r, r), jnp.float32),          # polar factor Z
         ],
-        interpret=interpret,
-    )(vs, ref.astype(jnp.float32), scales)
-
-
-def _fused_ring_remote_kernel(
-    nbr, v_wire, ref, out, circ, vbar, g, z, ssem, rsem, bar, *,
-    m: int, ns_iters: int, pivot_c: float, shift_c: float,
-):
-    """Compiled-ICI lane: hop payloads move by *remote* DMA, not staging.
-
-    Each shard holds only its own (d, r) wire basis; grid step i computes
-    on circ slot i % 2 while an async remote copy pushes that slot to the
-    right neighbor's slot (i+1) % 2 — the wire and the MXU overlap exactly
-    as in the interpret lane, but the "HBM-staged circulating buffer" is
-    the neighbor's VMEM across the ICI link.  A neighbor barrier
-    (semaphore handshake) before the first push keeps shard startup from
-    racing the first RDMA.  Full-basis hops: chunking below the basis
-    granularity stays in the staged lane, where the DMA engine is local.
-    """
-    i = pl.program_id(0)
-    me = nbr[0, 0]
-    right = nbr[0, 1]
-    slot = i % 2
-
-    @pl.when(i == 0)
-    def _start():
-        circ[pl.ds(0, 1)] = v_wire[...][None].astype(circ.dtype)
-        vbar[...] = jnp.zeros_like(vbar)
-        # Neighbor handshake: signal both sides, wait for both signals.
-        pltpu.semaphore_signal(bar, inc=1, device_id=right)
-        pltpu.semaphore_signal(bar, inc=1, device_id=(me - 1) % m)
-        pltpu.semaphore_wait(bar, 2)
-
-    @pl.when((i < m - 1) & (m > 1))
-    def _push():
-        rdma = pltpu.make_async_remote_copy(
-            src_ref=circ.at[pl.ds(slot, 1)],
-            dst_ref=circ.at[pl.ds((slot + 1) % 2, 1)],
-            send_sem=ssem.at[slot],
-            recv_sem=rsem.at[slot],
-            device_id=right,
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
-        )
-        rdma.start()
-
-    x = circ[pl.ds(slot, 1)][0].astype(jnp.float32)
-    gg = jnp.dot(x.T, ref[...], preferred_element_type=jnp.float32)
-    z[...] = _ns_polar_tile(gg, ns_iters)
-    vbar[...] += jnp.dot(x, z[...], preferred_element_type=jnp.float32)
-
-    @pl.when((i < m - 1) & (m > 1))
-    def _land():
-        rdma = pltpu.make_async_remote_copy(
-            src_ref=circ.at[pl.ds(slot, 1)],
-            dst_ref=circ.at[pl.ds((slot + 1) % 2, 1)],
-            send_sem=ssem.at[slot],
-            recv_sem=rsem.at[slot],
-            device_id=right,
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
-        )
-        rdma.wait()
-
-    @pl.when(i == m - 1)
-    def _tail():
-        vb = vbar[...] / m
-        s1 = jnp.dot(vb.T, vb, preferred_element_type=jnp.float32)
-        w1 = _cholqr_inverse_factor(s1, pivot_c=pivot_c, shift_c=shift_c)
-        q1 = jnp.dot(vb, w1, preferred_element_type=jnp.float32)
-        s2 = jnp.dot(q1.T, q1, preferred_element_type=jnp.float32)
-        w2 = _cholqr_inverse_factor(s2, pivot_c=pivot_c, shift_c=shift_c)
-        out[...] = jnp.dot(q1, w2, preferred_element_type=jnp.float32)
-
-
-def fused_ring_round_remote(
-    v_local: jax.Array,
-    ref: jax.Array,
-    *,
-    axis_name: str,
-    ns_iters: int = _DEFAULT_NS_ITERS,
-) -> jax.Array:
-    """One fused ring round with the hops on real ICI (compiled TPU only).
-
-    Call inside ``shard_map`` on a TPU mesh axis; each shard contributes
-    its local (d, r) f32 basis and the m-1 hops are in-kernel remote DMAs
-    to the right neighbor (see ``_fused_ring_remote_kernel``).  Exact-wire
-    (comm_bits=32) only — the quantized tiers ride the staged lane, whose
-    all-gather wire is already the ring's hop volume.  Off-TPU this lane
-    is untestable (remote DMA has no interpreter) and the suite skips it;
-    it exists so the schedule has a compiled-ICI home
-    (tests/test_fused_ring.py's TPU-marked lane).
-    """
-    from repro.compat import axis_size
-    from repro.kernels.ops import on_tpu
-
-    if not on_tpu():
-        raise NotImplementedError(
-            "fused_ring_round_remote needs real ICI (remote DMA); off-TPU "
-            "use the staged lane (repro.comm.ring.fused_ring_rounds)"
-        )
-    d, r = v_local.shape
-    m = axis_size(axis_name)
-    me = jax.lax.axis_index(axis_name)
-    nbr = jnp.stack([me, (me + 1) % m]).astype(jnp.int32)[None]
-    eps = float(jnp.finfo(jnp.float32).eps)
-    pivot_c, shift_c = r * eps, 11.0 * (d + r + 1) * eps
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(m,),
-        in_specs=[
-            pl.BlockSpec((d, r), lambda i, nbr_ref: (0, 0)),
-            pl.BlockSpec((d, r), lambda i, nbr_ref: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((d, r), lambda i, nbr_ref: (0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, d, r), jnp.float32),   # circulating double buffer
-            pltpu.VMEM((d, r), jnp.float32),      # resident running V̄
-            pltpu.VMEM((r, r), jnp.float32),
-            pltpu.VMEM((r, r), jnp.float32),
-            pltpu.SemaphoreType.DMA((2,)),        # send
-            pltpu.SemaphoreType.DMA((2,)),        # recv
-            pltpu.SemaphoreType.REGULAR,          # neighbor barrier
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(
-            _fused_ring_remote_kernel, m=m,
-            ns_iters=ns_iters, pivot_c=pivot_c, shift_c=shift_c,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_mosaic_vmem_limit(
+                m, dp, chunk, r, jnp.dtype(vs.dtype).itemsize
+            ),
         ),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((d, r), jnp.float32),
-        compiler_params=pltpu.TPUCompilerParams(collective_id=0),
-    )(nbr, v_local.astype(jnp.float32), ref.astype(jnp.float32))
+        interpret=interpret,
+    )(
+        vs,
+        ref.astype(jnp.float32).reshape(nc, chunk, r),
+        scales.astype(jnp.float32).reshape(m, 1, r),
+    )
+    return out.reshape(dp, r)[:d]

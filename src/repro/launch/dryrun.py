@@ -1,12 +1,13 @@
 import os
 os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={os.environ.get('REPRO_DRYRUN_DEVICES', '512')} " + os.environ.get("XLA_FLAGS", "")
+os.environ["JAX_PLATFORMS"] = "cpu"  # placeholder devices; never the chip
 
 """Multi-pod dry-run: lower + compile every (architecture x input shape) on
 the production meshes, record memory/cost analysis + collective bytes.
 
 MUST be run as a script / module entry (`python -m repro.launch.dryrun`):
-the XLA_FLAGS line above executes before any jax import, giving the CPU
-platform 512 placeholder devices so `jax.make_mesh((2,16,16))` can build the
+the environment lines above execute before any jax import, pinning the CPU
+platform and giving it 512 placeholder devices so `jax.make_mesh((2,16,16))` can build the
 production mesh.  Nothing is allocated: inputs and parameters are
 ShapeDtypeStructs end to end.
 

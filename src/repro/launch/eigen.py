@@ -43,6 +43,7 @@ from repro.core import (
 )
 from repro.comm.topology import DATA_AXIS, POD_AXIS
 from repro.data import synthetic as syn
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_aggregation_mesh, make_host_mesh
 
 log = logging.getLogger("repro.eigen")
@@ -178,10 +179,12 @@ def run(
     v_dist.block_until_ready()
     t_dist = time.perf_counter() - t0
 
+    # The references use the job's own eigensolver: a dense eigh at the
+    # paper_pca width (d=8192) takes many minutes to compile for a TPU.
     xs = samples.reshape(m, n_per_shard, d)
     covs = jax.vmap(lambda x: empirical_covariance(x))(xs)
-    v_cent, _ = central_estimate(covs, r)
-    vs = local_bases(covs, r)
+    v_cent, _ = central_estimate(covs, r, method=solver, iters=iters)
+    vs = local_bases(covs, r, method=solver, iters=iters)
     stats = {
         "m": m,
         "n": n_per_shard,
@@ -316,6 +319,7 @@ def main():
                          "--stream lane (default: STEPS // 4)")
     args = ap.parse_args()
     logging.basicConfig(level=logging.INFO)
+    use_compile_cache()
     plan = "auto" if args.plan == "auto" else None
     # Under --plan auto, unspecified/"auto" flags are free axes the
     # planner decides; an explicitly passed concrete flag is a pin.

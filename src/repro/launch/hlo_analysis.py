@@ -55,53 +55,101 @@ def _shape_bytes(dtype: str, dims: str) -> int:
     return n * _DTYPE_BYTES[dtype]
 
 
+_COMPUTATION_RE = re.compile(r"^(ENTRY\s+)?%[\w.\-]+\s*\(.*->.*\{\s*$")
+_NAME_RE = re.compile(r"%([\w.\-]+)")
+
+
+def _split_instruction(line: str):
+    """``(name, type_text, op, operand_text)`` of one HLO instruction line,
+    or None.  ``type_text`` may be a tuple ``(f32[..], s8[..])``."""
+    ls = line.strip()
+    if ls.startswith("ROOT "):
+        ls = ls[5:]
+    m = re.match(r"%([\w.\-]+)\s*=\s*", ls)
+    if not m:
+        return None
+    rest = ls[m.end():]
+    if rest.startswith("("):
+        depth = 0
+        for i, ch in enumerate(rest):
+            depth += ch == "("
+            depth -= ch == ")"
+            if depth == 0:
+                break
+        type_text, rest = rest[: i + 1], rest[i + 1:].lstrip()
+    else:
+        type_text, _, rest = rest.partition(" ")
+    op = re.match(r"([a-z][a-z0-9\-]*)\(", rest)
+    if not op:
+        return None
+    depth, args = 0, []
+    for ch in rest[op.end() - 1:]:
+        depth += ch == "("
+        depth -= ch == ")"
+        if depth == 0:
+            break
+        args.append(ch)
+    return m.group(1), type_text, op.group(1), "".join(args[1:])
+
+
+def _top_level_operands(arg_text: str):
+    """Split an operand list on the commas outside brackets and braces."""
+    out, depth, cur = [], 0, []
+    for ch in arg_text:
+        if ch in "([{":
+            depth += 1
+        elif ch in ")]}":
+            depth -= 1
+        if ch == "," and depth == 0:
+            out.append("".join(cur))
+            cur = []
+        else:
+            cur.append(ch)
+    if cur:
+        out.append("".join(cur))
+    return [o.strip() for o in out if o.strip()]
+
+
+def _type_bytes(type_text: str) -> int:
+    return sum(_shape_bytes(dt, dims) for dt, dims in _SHAPE_RE.findall(type_text))
+
+
 def collective_bytes(hlo_text: str) -> Dict[str, int]:
     """Per-device operand bytes by collective kind, from partitioned HLO.
 
-    We parse each op line of the form
-        %name = <out_shape> all-reduce(<operand shapes ...>), ...
-    and sum the OPERAND shape bytes (what each device contributes to the
-    wire).  ``-start`` async variants are counted; ``-done`` ops are not
-    (they carry the same buffers).
+    For each collective instruction we sum the bytes of its OPERANDS —
+    what each device contributes to the wire.  An operand printed with
+    its shape (``f32[96,4]{1,0} %x``) is read in place; one printed by
+    name alone (``%bitcast``, ``%param.1``, as current XLA does) is
+    resolved to the type of the instruction that defines it in the same
+    computation.  ``-start`` async variants are counted; ``-done`` ops are
+    not (they carry the same buffers).
     """
     out: Dict[str, int] = {k: 0 for k in COLLECTIVE_OPS}
+    defs: Dict[str, str] = {}
     for line in hlo_text.splitlines():
-        ls = line.strip()
-        m = re.search(r"=\s+\S+\s+([a-z\-]+)\(", ls)
-        if not m:
+        if _COMPUTATION_RE.match(line.strip()):
+            defs = {}
             continue
-        op = m.group(1)
+        inst = _split_instruction(line)
+        if inst is None:
+            continue
+        name, type_text, op, arg_text = inst
+        defs[name] = type_text
         base = op[:-6] if op.endswith("-start") else op
         if base not in COLLECTIVE_OPS:
             continue
-        # operand shapes: inside the call parens
-        paren = ls[ls.index(op) + len(op):]
-        # first (...) group operands; shapes appear as dtype[dims]
-        depth = 0
-        arglist = []
-        for ch in paren:
-            if ch == "(":
-                depth += 1
-                if depth == 1:
-                    continue
-            if ch == ")":
-                depth -= 1
-                if depth == 0:
-                    break
-            if depth >= 1:
-                arglist.append(ch)
-        args = "".join(arglist)
-        bytes_ = sum(
-            _shape_bytes(dt, dims) for dt, dims in _SHAPE_RE.findall(args)
-        )
-        if bytes_ == 0:
-            # shapes may be elided in operands ("%x.3"); fall back to the
-            # output shape on the lhs.
-            lhs = ls.split("=", 1)[1]
-            m2 = _SHAPE_RE.search(lhs)
-            if m2:
-                bytes_ = _shape_bytes(m2.group(1), m2.group(2))
-        out[base] += bytes_
+        for operand in _top_level_operands(arg_text):
+            if _SHAPE_RE.search(operand):
+                out[base] += _type_bytes(operand)
+                continue
+            ref = _NAME_RE.search(operand)
+            if ref is None or ref.group(1) not in defs:
+                raise ValueError(
+                    f"{op} operand {operand!r} has no shape and no "
+                    f"definition before it: {line.strip()[:200]}"
+                )
+            out[base] += _type_bytes(defs[ref.group(1)])
     return out
 
 

@@ -4,9 +4,9 @@
 this module touches no jax device state — required because the dry-run must
 set XLA_FLAGS before the first device query.
 
-All meshes are built through ``repro.compat.make_mesh`` so axis types are
-requested as 'auto' on JAX versions that have the concept and omitted on
-versions that don't.
+Every mesh is built by ``make_mesh``: ``jax.make_mesh`` with all axes
+``AxisType.Auto``, so sharding stays compiler-propagated as the
+collectives here expect.
 """
 
 from __future__ import annotations
@@ -14,8 +14,8 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
 
-from repro import compat
 from repro.comm.topology import DATA_AXIS, MODEL_AXIS, POD_AXIS
 
 
@@ -27,13 +27,16 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
         (POD_AXIS, DATA_AXIS, MODEL_AXIS)
         if multi_pod else (DATA_AXIS, MODEL_AXIS)
     )
-    return compat.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(
-    shape: Tuple[int, ...], axes: Tuple[str, ...]
+    shape: Tuple[int, ...], axes: Tuple[str, ...], *, devices=None
 ) -> jax.sharding.Mesh:
-    return compat.make_mesh(shape, axes)
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes),
+        devices=devices,
+    )
 
 
 def make_host_mesh(
@@ -52,7 +55,7 @@ def make_host_mesh(
             model = 1
     data = data or (n // model)
     assert data * model == n, (data, model, n)
-    return compat.make_mesh((data, model), (DATA_AXIS, MODEL_AXIS))
+    return make_mesh((data, model), (DATA_AXIS, MODEL_AXIS))
 
 
 def make_aggregation_mesh(
@@ -69,13 +72,13 @@ def make_aggregation_mesh(
     """
     m = m or len(jax.devices())
     if pods is None:
-        return compat.make_mesh((m,), (DATA_AXIS,))
+        return make_mesh((m,), (DATA_AXIS,))
     pods = int(pods)
     if pods < 1 or m % pods:
         raise ValueError(
             f"pods={pods} does not tile m={m} into equal pods"
         )
-    return compat.make_mesh((pods, m // pods), (POD_AXIS, DATA_AXIS))
+    return make_mesh((pods, m // pods), (POD_AXIS, DATA_AXIS))
 
 
 def data_axes(mesh: jax.sharding.Mesh) -> Tuple[str, ...]:

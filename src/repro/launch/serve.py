@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, get_reduced_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.sharding import batch_shardings, param_shardings
 from repro.launch.steps import jit_decode_step
@@ -115,7 +116,9 @@ def serve_subspace(
     every shard ``rows_per_step`` rows per step; the service refreshes on
     the cadence; then ``queries`` query rows are projected through the
     served basis in ``batch``-row waves and the projection throughput is
-    reported next to the refresh stats.
+    reported next to the refresh stats.  Each refresh solves locally by
+    subspace iteration, the distributed-PCA CLI's solver too (a dense
+    eigh at d=8192 takes many minutes to compile for a TPU).
     """
     from repro.comm.topology import DATA_AXIS
     from repro.data import synthetic as syn
@@ -125,7 +128,7 @@ def serve_subspace(
     mesh = mesh or make_aggregation_mesh()
     m = mesh.shape[DATA_AXIS] * mesh.shape.get("pod", 1)
     svc = SubspaceService(
-        mesh, d, r, cadence=cadence, topology=topology,
+        mesh, d, r, cadence=cadence, solver="subspace", topology=topology,
         comm_bits=comm_bits, plan=plan,
     )
     key = jax.random.PRNGKey(seed)
@@ -184,6 +187,7 @@ def main():
     ap.add_argument("--queries", type=int, default=4096)
     args = ap.parse_args()
     logging.basicConfig(level=logging.INFO, format="%(asctime)s %(message)s")
+    use_compile_cache()
     if args.subspace:
         _, stats = serve_subspace(
             d=args.d, r=args.r, steps=args.steps,

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.launch.mesh import data_axes
 from repro.launch.sharding import (
     batch_shardings,
@@ -432,7 +431,7 @@ def jit_eigen_steps(
     bs_specs = jax.tree.map(lambda s: _manual_only_spec(s, dax), bs)
     scalar_spec = P()
 
-    train_sm = compat.shard_map(
+    train_sm = jax.shard_map(
         train_body,
         mesh=mesh,
         in_specs=(ps_specs, os_specs, bs_specs),
@@ -440,7 +439,7 @@ def jit_eigen_steps(
         axis_names=set(dax),
         check_vma=False,
     )
-    refresh_sm = compat.shard_map(
+    refresh_sm = jax.shard_map(
         refresh_body,
         mesh=mesh,
         in_specs=(ps_specs, os_specs, bs_specs, scalar_spec),

@@ -38,7 +38,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.comm import axis_size
 from repro.core.distributed import procrustes_average_collective
 
 
@@ -162,7 +161,7 @@ def compress_and_reduce(
     (d x n) and the low-rank coordinates (r x n) the Adam moments live in.
     Communication: psum of r*n words instead of d*n.
     """
-    m = axis_size(axis_name)  # static: no all-reduce on the wire
+    m = jax.lax.axis_size(axis_name)  # static: no all-reduce on the wire
     g_eff = g_local.astype(jnp.float32) + state["err"]
     p = state["basis"]
     if g_local.ndim == 2:

@@ -12,7 +12,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.distributed import procrustes_average_collective
 from repro.core.subspace import local_eigenbasis
 from repro.data.synthetic import truncated_second_moment
@@ -65,7 +64,7 @@ def distributed_spectral_init(
         return out[None]
 
     fn = jax.jit(
-        shard_map(
+        jax.shard_map(
             shard_fn,
             mesh=mesh,
             in_specs=(P(data_axis, None), P(data_axis)),

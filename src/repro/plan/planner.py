@@ -64,6 +64,7 @@ from repro.core.procrustes import DEFAULT_NS_ITERS, POLAR_METHODS
 from repro.plan.calibration import Calibration
 from repro.plan.roofline import DeviceModel, device_model
 from repro.kernels.ops import BACKENDS as BACKEND_CHOICES  # includes "auto"
+from repro.kernels.procrustes_align import fused_ring_vmem_bytes
 
 __all__ = [
     "Plan",
@@ -331,9 +332,14 @@ def score_cells(
 
 
 def _default_device_kind() -> str:
+    """The chip's own ``device_kind`` on a TPU (``device_model`` knows
+    which generations it can price); the backend name elsewhere."""
     import jax
 
-    return jax.default_backend()
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return jax.devices()[0].device_kind
+    return backend
 
 
 def _score_one(
@@ -386,10 +392,11 @@ def _score_one(
             feasible = False
             notes.append("pallas compiles on TPU only")
     if fused_ring:
-        # §3.3: three wire-width hop slots plus the f32 running V̄ / ref /
+        # §3.3: the wire-width hop buffers plus the f32 running V̄ / ref /
         # out tiles live in VMEM for the whole launch; past the envelope
-        # the one-launch schedule cannot be scheduled at all.
-        vmem_bytes = basis * (3 * cb / 8.0 + 3 * 4.0)
+        # the one-launch schedule cannot be scheduled at all (the compiled
+        # kernel raises on the same rule).
+        vmem_bytes = fused_ring_vmem_bytes(d, r, cb)
         if vmem_bytes > device.vmem_cap_bytes:
             feasible = False
             notes.append(

@@ -30,6 +30,7 @@ from typing import Dict, List, Optional
 __all__ = [
     "DeviceModel",
     "DEVICE_MODELS",
+    "TPU_KINDS",
     "device_model",
     "TPU_V5E",
     "CPU_HOST",
@@ -124,8 +125,10 @@ class DeviceModel:
         return dataclasses.replace(self, **updates) if updates else self
 
 
-# TPU v5e target, from the brief (these three are the legacy
-# ``hlo_analysis`` constants — single home is now here).
+# TPU v5e.  Peaks: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s
+# bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s chip-to-chip interconnect
+# (four links, so 50 GB/s each).  The latency terms and the 16 MiB VMEM
+# envelope (the default scoped-VMEM limit) are priors, not measurements.
 TPU_V5E = DeviceModel(
     kind="tpu",
     peak_flops=197e12,   # bf16 per chip
@@ -181,11 +184,24 @@ DEVICE_MODELS: Dict[str, DeviceModel] = {
     m.kind: m for m in (TPU_V5E, CPU_HOST, GPU_GENERIC)
 }
 
+# ``jax.devices()[0].device_kind`` of each TPU generation priced above.
+TPU_KINDS: Dict[str, DeviceModel] = {
+    "TPU v5 lite": TPU_V5E,
+    "TPU v5e": TPU_V5E,
+}
+
 
 def device_model(kind: str) -> DeviceModel:
-    """Model for a ``jax.default_backend()``-style kind; unknown kinds get
-    the CPU model (conservative: no kernels, cheap LAPACK)."""
-    return DEVICE_MODELS.get(kind, CPU_HOST)
+    """Model for a backend name (``"tpu"``, ``"cpu"``, ``"gpu"``) or a TPU
+    ``device_kind`` string.  A kind not in the tables is an error: a chip
+    priced with another chip's peaks gives plans nobody measured."""
+    model = DEVICE_MODELS.get(kind) or TPU_KINDS.get(kind)
+    if model is None:
+        raise ValueError(
+            f"no device model for kind {kind!r}; known: "
+            f"{sorted(DEVICE_MODELS) + sorted(TPU_KINDS)}"
+        )
+    return model
 
 
 # Legacy names (the brief's TPU v5e numbers); ``repro.launch.hlo_analysis``

@@ -42,7 +42,6 @@ import jax
 from jax.sharding import PartitionSpec as P
 
 from repro.comm.membership import Membership
-from repro.compat import shard_map
 from repro.plan.planner import Plan, plan_aggregation
 from repro.runtime.straggler import StepTimer
 
@@ -236,7 +235,7 @@ def elastic_pca(
     # sharded along the axis.  The planned backend routes it, like the
     # driver in ``core.distributed``.
     local_fn = jax.jit(
-        shard_map(
+        jax.shard_map(
             lambda x: _local_pca_basis(
                 x, r, solver=solver, iters=iters, backend=pl.backend
             )[None],
@@ -259,7 +258,7 @@ def elastic_pca(
                 )
                 return out[None]
 
-            wrapped = shard_map(
+            wrapped = jax.shard_map(
                 fn, mesh=mesh, in_specs=P(data_axis, None, None),
                 out_specs=P(data_axis, None, None), check_vma=False,
             )
@@ -272,7 +271,7 @@ def elastic_pca(
             )
             return out[None]
 
-        wrapped = shard_map(
+        wrapped = jax.shard_map(
             fn, mesh=mesh,
             in_specs=(P(data_axis, None, None), P(None, None)),
             out_specs=P(data_axis, None, None), check_vma=False,

@@ -60,7 +60,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.comm import DATA_AXIS, POD_AXIS
 from repro.comm.membership import Membership, resolve_membership
-from repro.compat import shard_map
 from repro.core.distributed import (
     _agg_axes,
     _hier_requested,
@@ -292,13 +291,13 @@ class SubspaceService:
             return out[None]
 
         if with_ref:
-            fn = jax.jit(shard_map(
+            fn = jax.jit(jax.shard_map(
                 shard_fn, mesh=self.mesh,
                 in_specs=(P(axes, None, None), P(None, None)),
                 out_specs=P(axes, None, None), check_vma=False,
             ))
         else:
-            fn = jax.jit(shard_map(
+            fn = jax.jit(jax.shard_map(
                 lambda c: shard_fn(c, None), mesh=self.mesh,
                 in_specs=P(axes, None, None),
                 out_specs=P(axes, None, None), check_vma=False,

@@ -19,7 +19,8 @@ SRC = os.path.join(REPO, "src")
 
 
 def run_with_devices(code: str, n_devices: int = 8, timeout: int = 600) -> str:
-    """Run a python snippet in a subprocess with N fake CPU devices.
+    """Run a python snippet in a subprocess with N fake CPU devices (the
+    CPU platform is pinned, so the child never touches an accelerator).
 
     Returns captured stdout; raises on non-zero exit (with stderr attached).
     """
@@ -31,6 +32,7 @@ def run_with_devices(code: str, n_devices: int = 8, timeout: int = 600) -> str:
         )
     )
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"  # virtual devices; never the chip
     proc = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(code)],
         env=env,

@@ -221,7 +221,9 @@ def test_comm_bits_parity_single_device(topology, comm_bits):
     m=1, so they get their documented looser bounds."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import make_mesh, shard_map
+    from jax import shard_map
+
+    from repro.launch.mesh import make_mesh
     from repro.core import refinement_rounds
     from repro.core.distributed import procrustes_average_collective
 
@@ -254,7 +256,8 @@ def test_comm_bits_parity_cube_eight_devices():
         """
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.compat import make_mesh, shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.core import refinement_rounds
         from repro.core.distributed import procrustes_average_collective
         from repro.core.metrics import subspace_dist64

@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.checkpoint import (
     CheckpointManager,
     latest_step,
@@ -68,7 +68,7 @@ def test_elastic_restore_resharding(tmp_path):
     """Save replicated, restore with an explicit (1-device) sharding."""
     t = _tree()
     save_checkpoint(str(tmp_path), 3, t)
-    mesh = compat.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     sh = jax.tree.map(
         lambda _: jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec()), t
     )

@@ -96,7 +96,8 @@ def test_sign_average_collective_eight_devices():
         """
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.compat import make_mesh, shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.core import sign_average_collective
         from repro.core import procrustes
 
@@ -144,7 +145,8 @@ def test_collective_orth_switch_eight_devices():
         """
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.compat import make_mesh, shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.core import procrustes_fix_average
         from repro.core.distributed import procrustes_average_collective
 

@@ -12,7 +12,7 @@ import pytest
 
 from conftest import run_with_devices
 
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.core import (
     distributed_pca,
     distributed_pca_from_covs,
@@ -42,7 +42,7 @@ def test_eight_device_matches_serial():
     out = run_with_devices(
         """
         import jax, jax.numpy as jnp
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.core import (distributed_pca, empirical_covariance,
                                 local_bases, procrustes_fix_average,
                                 iterative_refinement)
@@ -82,7 +82,7 @@ def test_from_covs_and_subspace_solver():
     out = run_with_devices(
         """
         import jax, jax.numpy as jnp
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.core import (distributed_pca_from_covs, empirical_covariance,
                                 local_bases, procrustes_fix_average, dist_2)
         from repro.data import synthetic as syn

@@ -9,7 +9,6 @@ import sys
 import pytest
 
 from conftest import SRC
-from repro.compat import HAS_NATIVE_SHARD_MAP
 
 
 def _run_dryrun(args, tmp_path, devices=8):
@@ -25,14 +24,6 @@ def _run_dryrun(args, tmp_path, devices=8):
 
 
 @pytest.mark.slow
-@pytest.mark.xfail(
-    not HAS_NATIVE_SHARD_MAP,
-    reason="partial-manual shard_map (manual data axes, auto model axis) "
-    "aborts the SPMD partitioner on jax 0.4.x (hlo_sharding_util "
-    "IsManualSubgroup check); the experimental `auto=` path of that "
-    "generation cannot lower the hybrid eigen train step",
-    strict=False,
-)
 def test_dryrun_eigen_variant(tmp_path):
     out = _run_dryrun(
         ["--arch", "whisper-tiny", "--shape", "train_4k", "--eigen",

@@ -36,7 +36,8 @@ def test_refresh_basis_rotation_invariance():
         """
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.compat import make_mesh, shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.core import dist_2
         from repro.optim.eigen_compress import (EigenCompressConfig,
                                                 refresh_basis, _local_basis)

@@ -219,7 +219,7 @@ def _samples(m, n, d, seed=0):
 def test_elastic_pca_matches_distributed_pca_when_healthy():
     """No injector, no monitor: elastic_pca is distributed_pca plus a
     decision log with the single 'initial' event."""
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.core.distributed import distributed_pca
     from repro.runtime.elastic import elastic_pca
 
@@ -238,7 +238,7 @@ def test_elastic_pca_matches_distributed_pca_when_healthy():
 def test_elastic_pca_straggler_escalation_replans():
     """A slow group trips the monitor; the pending re-plan is honoured at
     the next group boundary and the user's own callback still fires."""
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.runtime.elastic import elastic_pca
     from repro.runtime.straggler import StragglerMonitor
 
@@ -366,7 +366,8 @@ def test_masked_parity_cube_eight_devices():
         """
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.compat import make_mesh, shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.comm import Membership
         from repro.core import refinement_rounds
         from repro.core.distributed import procrustes_average_collective
@@ -416,7 +417,7 @@ def test_elastic_midrun_kill_matches_composed_oracle():
     out = run_with_devices(
         """
         import jax, jax.numpy as jnp
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.comm import Membership
         from repro.core import refinement_rounds
         from repro.core.distributed import _local_pca_basis
@@ -471,7 +472,7 @@ def test_elastic_recovery_rejoins_via_alignment():
     out = run_with_devices(
         """
         import jax, jax.numpy as jnp
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.core.metrics import subspace_dist64
         from repro.data import synthetic as syn
         from repro.runtime.elastic import elastic_pca
@@ -523,7 +524,8 @@ def test_masked_ring_hlo_bytes_match_cost_model():
         """
         import jax, jax.numpy as jnp, json
         from jax.sharding import PartitionSpec as P
-        from repro.compat import make_mesh, shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.comm import Membership
         from repro.core.distributed import procrustes_average_collective
         from repro.launch.hlo_analysis import collective_bytes

@@ -6,8 +6,8 @@ polar="newton-schulz", orth="cholesky-qr2"`` cell (DESIGN.md §3.3):
   * The ring-round kernel (``kernels.procrustes_align.fused_ring_round``)
     matches its XLA oracle (``kernels.ref.fused_ring_round``) on ragged
     shapes — including ``ring_chunk`` not dividing d and d < chunk (the
-    clamped-start + per-chunk freshness mask path) — and on every wire
-    dtype (f32 / bf16 / int8 + scales).
+    stack is zero-padded to whole chunks) — and on every wire dtype
+    (f32 / bf16 / int8 + scales).
   * ``n_iter`` rounds of ``repro.comm.ring.fused_ring_rounds`` lower to
     exactly ``n_iter`` pallas_calls with **zero XLA collectives and zero
     XLA compute between launches**: the wire is staged up front (error
@@ -20,8 +20,8 @@ polar="newton-schulz", orth="cholesky-qr2"`` cell (DESIGN.md §3.3):
     replicated across shards, and a degraded ring (dead shard) matches
     the fresh survivor-count oracle.
 
-Interpret-mode lanes run everywhere; the compiled-TPU remote-DMA lane
-(``fused_ring_round_remote``, hops on real ICI) is skipped off-TPU.
+These lanes run the kernel interpreted; ``tests/test_tpu_compile.py``
+compiles it for a described v5e.
 """
 
 import jax
@@ -34,7 +34,6 @@ from conftest import jaxpr_primitives, run_with_devices, subspace_dist64
 from repro.comm.quantize import PARITY_TOL, get_codec
 from repro.comm.ring import DEFAULT_RING_CHUNK, chunk_spans, fused_ring_rounds
 from repro.kernels import procrustes_align, ref
-from repro.kernels.ops import on_tpu
 
 # Primitives that must never appear in the fused path's jaxpr ("qr" is a
 # primitive name, not a substring — "sqrt" would false-alarm) plus the
@@ -55,16 +54,16 @@ def _stack(seed, m, d, r):
 @pytest.mark.parametrize(
     "m,d,r,chunk",
     [
-        (4, 96, 8, 40),     # chunk does not divide d (clamped-start path)
+        (4, 96, 8, 40),     # chunk does not divide d (zero-padded tail)
         (3, 33, 5, 8),      # ragged everything
-        (1, 7, 3, 16),      # d < chunk (single clamped chunk), m == 1
+        (1, 7, 3, 16),      # d < chunk (one chunk of d rows), m == 1
         (8, 128, 16, 128),  # chunk == d (one chunk per hop)
-        (2, 100, 4, 33),    # overlap rows on every chunk boundary
+        (2, 100, 4, 33),    # a one-row last chunk
     ],
 )
 def test_fused_ring_kernel_matches_oracle(m, d, r, chunk):
-    """Kernel == oracle to f32 roundoff on ragged shapes; the per-chunk
-    freshness mask makes re-read overlap rows contribute exact zeros."""
+    """Kernel == oracle to f32 roundoff on ragged shapes; the zero rows
+    that pad d to whole chunks contribute exact zeros."""
     vs = _stack(m * d + r, m, d, r)
     zk = procrustes_align.fused_ring_round(
         vs, vs[0], ring_chunk=chunk, interpret=True
@@ -118,6 +117,38 @@ def test_fused_ring_kernel_scale_validation():
         procrustes_align.fused_ring_round(
             vs.astype(jnp.float16), vs[0], interpret=True
         )
+
+
+@pytest.mark.parametrize("dtype,fits", [
+    (jnp.float32, False),   # (3*4 + 12) * 8192 * 128 B = 24 MiB
+    (jnp.int8, True),       # (3*1 + 12) * 8192 * 128 B = 15 MiB
+])
+def test_compiled_fused_ring_refuses_over_the_vmem_envelope(dtype, fits):
+    """Compiled, a working set over the planner's VMEM envelope raises a
+    ValueError naming it while tracing, before Mosaic sees the kernel; the
+    planner marks exactly that cell infeasible."""
+    from repro.plan.planner import score_cells
+
+    m, d, r = 4, 8192, 128
+    args = [jax.ShapeDtypeStruct((m, d, r), dtype),
+            jax.ShapeDtypeStruct((d, r), jnp.float32)]
+    if dtype == jnp.int8:
+        args.append(jax.ShapeDtypeStruct((m, r), jnp.float32))
+    trace = lambda: jax.eval_shape(
+        lambda *a: procrustes_align.fused_ring_round(*a, interpret=False),
+        *args,
+    )
+    bits = 32 if dtype == jnp.float32 else 8
+    cell = score_cells(
+        m=m, d=d, r=r, device_kind="tpu", backend="pallas", topology="ring",
+        polar="newton-schulz", orth="cholesky-qr2", comm_bits=bits,
+    )[0]
+    assert cell.feasible == fits
+    if fits:
+        assert trace().shape == (d, r)
+    else:
+        with pytest.raises(ValueError, match="VMEM envelope"):
+            trace()
 
 
 def test_chunk_spans_single_home():
@@ -276,37 +307,3 @@ def test_fused_ring_collective_degraded_membership():
     fields = dict(kv.split("=") for kv in out.strip().splitlines()[-1].split())
     assert float(fields["dist"]) <= 1e-5
     assert float(fields["rep"]) == 0.0
-
-
-# ---------------------------------------------------------------------------
-# Remote-DMA lane (hops on real ICI) — compiled TPU only.
-
-
-def test_remote_lane_raises_off_tpu():
-    if on_tpu():
-        pytest.skip("off-TPU guard test")
-    with pytest.raises(NotImplementedError):
-        procrustes_align.fused_ring_round_remote(
-            jnp.zeros((8, 4)), jnp.zeros((8, 4)), axis_name="mach"
-        )
-
-
-@pytest.mark.skipif(not on_tpu(), reason="remote DMA needs real ICI")
-def test_fused_ring_remote_compiled_tpu():
-    """The in-kernel remote-DMA ring matches the staged lane and the
-    serial oracle on a real TPU mesh."""
-    m = jax.device_count()
-    d, r = 1024, 16
-    vs = _stack(0, m, d, r)
-    oracle = ref.fused_ring_round(vs, vs[0])
-    got = jax.pmap(
-        lambda v: procrustes_align.fused_ring_round_remote(
-            v, vs[0], axis_name="mach"
-        ),
-        axis_name="mach",
-    )(vs)
-    assert subspace_dist64(oracle, got[0]) <= 1e-5
-    np.testing.assert_allclose(
-        np.asarray(got), np.broadcast_to(np.asarray(got[0]), got.shape),
-        atol=1e-6,
-    )

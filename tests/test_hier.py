@@ -166,7 +166,8 @@ def _qr_stack(m, d, r, seed=0):
 def test_hier_degenerate_mesh_matches_serial():
     """On the (1, 1) mesh the hier schedule is communication-free and
     must equal the serial refinement of the single basis."""
-    from repro.compat import make_mesh, shard_map
+    from jax import shard_map
+    from repro.launch.mesh import make_mesh
     from repro.core import refinement_rounds
     from repro.core.distributed import procrustes_average_collective
     from repro.core.metrics import subspace_dist64
@@ -186,7 +187,8 @@ def test_hier_degenerate_mesh_matches_serial():
 
 def test_hier_pod_axis_consistency_errors():
     """topology="hier" and pod_axis= go together, both ways."""
-    from repro.compat import make_mesh, shard_map
+    from jax import shard_map
+    from repro.launch.mesh import make_mesh
     from repro.core.distributed import procrustes_average_collective
 
     vs = _qr_stack(1, 32, 4)
@@ -212,7 +214,8 @@ def test_collective_dtype_preserved_at_lossy_tiers():
     comm_bits) arm — the wire codec's f32 staging (decode buffers, the
     psum reference broadcast) must cast back to the payload dtype.
     Matmul-only compute knobs so CPU LAPACK never sees bf16."""
-    from repro.compat import make_mesh, shard_map
+    from jax import shard_map
+    from repro.launch.mesh import make_mesh
     from repro.core.distributed import procrustes_average_collective
 
     vs = _qr_stack(1, 64, 4).astype(jnp.bfloat16)
@@ -283,7 +286,8 @@ def test_hier_parity_cube_eight_devices():
         """
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.compat import make_mesh, shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.comm import Membership
         from repro.core import refinement_rounds
         from repro.core.distributed import procrustes_average_collective
@@ -344,7 +348,8 @@ def test_hier_hlo_bytes_per_level_eight_devices():
         """
         import jax, jax.numpy as jnp, json
         from jax.sharding import PartitionSpec as P
-        from repro.compat import make_mesh, shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.comm import Membership
         from repro.core.distributed import procrustes_average_collective
         from repro.launch.hlo_analysis import collective_bytes

@@ -8,7 +8,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.configs import ARCHS, get_config, get_reduced_config
 from repro.models import (
     SHAPES,
@@ -64,7 +63,7 @@ def test_smoke_train_step_grads(arch):
     for g in flat:
         assert bool(jnp.all(jnp.isfinite(g))), f"{arch}: non-finite grad"
     # at least some gradient signal reaches the embedding
-    leaves = {jax.tree_util.keystr(k): v for k, v in compat.tree_flatten_with_path(grads)[0]}
+    leaves = {jax.tree_util.keystr(k): v for k, v in jax.tree.flatten_with_path(grads)[0]}
     emb = [v for k, v in leaves.items() if "embed" in k][0]
     assert float(jnp.abs(emb).max()) > 0
 

@@ -370,6 +370,29 @@ def test_calibration_from_committed_baseline():
     assert pl.backend in BACKENDS and pl.topology in TOPOLOGIES
 
 
+@pytest.mark.parametrize("kind", ["TPU v5 lite", "TPU v5e", "tpu"])
+def test_device_model_prices_v5e(kind):
+    """``jax.devices()[0].device_kind`` of a v5e (and the backend name the
+    tests pass) resolve to the one TPU model the table holds."""
+    from repro.plan.roofline import TPU_V5E
+
+    assert device_model(kind) is TPU_V5E
+
+
+@pytest.mark.parametrize("kind", ["TPU v4", "TPU v6 lite", "metal", ""])
+def test_device_model_refuses_unknown_kind(kind):
+    """An unknown chip is an error, never priced as a CPU."""
+    with pytest.raises(ValueError, match="no device model"):
+        device_model(kind)
+
+
+def test_default_device_kind_is_the_backend_off_tpu():
+    from repro.plan.planner import _default_device_kind
+
+    assert _default_device_kind() == jax.default_backend() == "cpu"
+    assert plan_aggregation(m=4, d=64, r=4).device_kind == "cpu"
+
+
 def test_calibration_degrades_to_noop():
     empty = Calibration.from_records("cpu", [])
     assert empty.cells == 0 and empty.dispatch_s is None
@@ -407,7 +430,9 @@ def test_plan_auto_single_device_parity_all_pins():
     the fast-lane slice of the acceptance parity suite."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import make_mesh, shard_map
+    from jax import shard_map
+
+    from repro.launch.mesh import make_mesh
     from repro.core import refinement_rounds
     from repro.core.distributed import procrustes_average_collective
 
@@ -449,7 +474,8 @@ def test_plan_auto_parity_cube_eight_devices():
         """
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.compat import make_mesh, shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.core import refinement_rounds
         from repro.core.distributed import procrustes_average_collective
         from repro.core.metrics import subspace_dist64

@@ -94,7 +94,8 @@ def test_collective_bytes_real_module():
         """
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.compat import make_mesh, shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.launch.hlo_analysis import collective_bytes
         mesh = make_mesh((4,), ("d",))
         def f(x):

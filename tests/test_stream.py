@@ -75,9 +75,7 @@ def _chunks(x, k):
 
 def test_chunked_equals_oneshot_bitwise_f64():
     """k-chunked accumulation == one-shot empirical_covariance, every bit."""
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64():
         x = _int_rows(0, n=257, d=24)
         want = np.asarray(empirical_covariance(jnp.asarray(x)))
         assert want.dtype == np.float64

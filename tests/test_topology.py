@@ -20,7 +20,7 @@ Five properties are pinned down:
 4. Ring structure: the ring path's compiled HLO contains no all-gather
    collective and never materializes an (m, d, r) stack (asserted against
    the gather topology as a positive control for the methodology), and
-   ``axis_size`` is static — no all-reduce of ones in the jaxpr.
+   ``jax.lax.axis_size`` is static — no all-reduce of ones in the jaxpr.
 
 Multi-device cases run in a subprocess with fake CPU devices
 (``conftest.run_with_devices``), per the project rules.
@@ -78,7 +78,8 @@ def test_resolve_topology_invalid_raises():
 
 
 def test_collective_invalid_topology_raises_at_trace():
-    from repro.compat import make_mesh, shard_map
+    from jax import shard_map
+    from repro.launch.mesh import make_mesh
     from jax.sharding import PartitionSpec as P
 
     mesh = make_mesh((1,), ("data",))
@@ -156,6 +157,49 @@ def test_comm_cost_bits_formulas():
     assert 0.25 <= ratio <= 0.26
 
 
+_NAMED_OPERAND_HLO = """
+HloModule m
+
+%region (a: f32[], b: f32[]) -> f32[] {
+  %a = f32[] parameter(0)
+  %b = f32[] parameter(1)
+  ROOT %add = f32[] add(%a, %b)
+}
+
+ENTRY %main (param.1: f32[96,4]) -> f32[8,96,4] {
+  %param.1 = f32[96,4]{1,0} parameter(0)
+  %bitcast = f32[1,96,4]{2,1,0} bitcast(%param.1)
+  %all-gather = f32[8,96,4]{2,1,0} all-gather(%bitcast), dimensions={0}
+  %ppermute = f32[96,4]{1,0} collective-permute(%param.1), source_target_pairs={{0,1}}
+  %ag-start = (s8[96,4]{1,0}, s8[768,4]{1,0}) all-gather-start(s8[96,4]{1,0} %q), dimensions={0}
+  %ag-done = s8[768,4]{1,0} all-gather-done(%ag-start)
+  %psum = f32[96,4]{1,0} all-reduce(%ppermute), to_apply=%region
+  ROOT %out = f32[8,96,4]{2,1,0} copy(%all-gather)
+}
+"""
+
+
+def test_collective_bytes_resolves_named_operands():
+    """Operands printed by name alone are priced at the shape of the
+    instruction that defines them (an all-gather's input, not its m-times
+    larger output); operands printed with a shape are read in place, and
+    ``-done`` halves of async pairs are not counted again."""
+    from repro.launch.hlo_analysis import collective_bytes
+
+    got = collective_bytes(_NAMED_OPERAND_HLO)
+    assert got["all-gather"] == 96 * 4 * 4 + 96 * 4
+    assert got["collective-permute"] == 96 * 4 * 4
+    assert got["all-reduce"] == 96 * 4 * 4
+
+
+def test_collective_bytes_refuses_an_unknown_operand():
+    from repro.launch.hlo_analysis import collective_bytes
+
+    text = "ENTRY %e () -> f32[4] {\n  ROOT %x = f32[4]{0} all-reduce(%nowhere)\n}"
+    with pytest.raises(ValueError, match="no shape"):
+        collective_bytes(text)
+
+
 @pytest.mark.slow
 def test_comm_model_matches_compiled_hlo_eight_devices():
     """Byte-exact: the model's per-topology HLO prediction equals the
@@ -166,7 +210,8 @@ def test_comm_model_matches_compiled_hlo_eight_devices():
         import json
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.compat import make_mesh, shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.core.distributed import procrustes_average_collective
         from repro.launch.hlo_analysis import collective_bytes
 
@@ -219,7 +264,8 @@ def test_comm_model_bits_match_compiled_hlo_eight_devices():
         import json
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.compat import make_mesh, shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.core.distributed import procrustes_average_collective
         from repro.launch.hlo_analysis import collective_bytes
 
@@ -270,7 +316,8 @@ def test_int8_ring_wire_acceptance_ratio():
         """
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.compat import make_mesh, shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.core.distributed import procrustes_average_collective
         from repro.launch.hlo_analysis import collective_bytes
 
@@ -310,7 +357,8 @@ def test_single_device_all_cells_match_serial():
     the m=1 serial rounds — fast-lane coverage of all the dispatch plumbing
     (the ring runs zero hops, gather stacks one basis, psum psums with
     itself), including a ring chunk that does not divide d."""
-    from repro.compat import make_mesh, shard_map
+    from jax import shard_map
+    from repro.launch.mesh import make_mesh
     from jax.sharding import PartitionSpec as P
 
     d, r = 96, 4
@@ -341,7 +389,8 @@ def test_topology_backend_cube_eight_devices():
         """
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.compat import make_mesh, shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.core import refinement_rounds
         from repro.core.distributed import procrustes_average_collective
         from repro.core.metrics import subspace_dist64
@@ -378,7 +427,8 @@ def test_ring_matches_oracle_with_newton_schulz_cholqr2():
         """
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.compat import make_mesh, shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.core import refinement_rounds
         from repro.core.distributed import procrustes_average_collective
         from repro.core.metrics import subspace_dist64
@@ -411,7 +461,7 @@ def test_distributed_pca_topology_switch_eight_devices():
     out = run_with_devices(
         """
         import jax, jax.numpy as jnp
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.core import distributed_pca
         from repro.data import synthetic as syn
 
@@ -447,7 +497,8 @@ def test_ring_hlo_no_allgather_no_stack_eight_devices():
         """
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.compat import make_mesh, shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.core.distributed import procrustes_average_collective
         from repro.launch.hlo_analysis import collective_bytes
 
@@ -517,15 +568,14 @@ def test_ring_jaxpr_has_no_all_gather_and_no_stack():
 
 
 def test_axis_size_is_static_no_collective():
-    """``axis_size`` folds to the mesh's static size at trace time: no
-    psum (or any collective) reaches the jaxpr, and the value is a Python
-    int usable for Python-level loop bounds (the ring's hop count)."""
-    from repro.comm import axis_size
-
+    """``jax.lax.axis_size`` folds to the mesh's static size at trace
+    time: no psum (or any collective) reaches the jaxpr, and the value is
+    a Python int usable for Python-level loop bounds (the ring's hop
+    count)."""
     sizes = []
 
     def f(x):
-        m = axis_size("mach")
+        m = jax.lax.axis_size("mach")
         sizes.append(m)
         return x * m
 
