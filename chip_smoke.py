@@ -209,7 +209,7 @@ def phase_service(mesh):
             queries=4096, batch=batch, mesh=mesh, seed=sm.seed,
         )
         for k in ("step", "rows_seen", "refreshes", "staleness",
-                  "ingest_s", "query_s", "queries_per_s"):
+                  "queries", "syncs", "sync_wait_s"):
             print(f"[{name}] {k}={st[k]}")
         sm.check(name, "refreshes", st["refreshes"], ">= 4",
               st["refreshes"] >= 4)

@@ -47,6 +47,8 @@ them for end-to-end use.
 
 from __future__ import annotations
 
+import itertools
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
@@ -84,6 +86,10 @@ __all__ = [
 # shards fold their axis index (and round counter) into these.
 _PSUM_SALT = 0x5053554D
 _GATHER_SALT = 0x47415452
+
+# The ``job`` id of each ``distributed_pca`` call in this process, carried
+# by the call's profiler spans.
+_JOB_IDS = itertools.count()
 
 
 def _align_local(
@@ -406,38 +412,46 @@ def distributed_pca(
     and shards the samples over both axes pod-major, so ``membership``
     then describes all pods*local machines in that order.  Returns the
     (d, r) estimate.
+
+    The call's host time is the profiler span ``repro.pca``; plan
+    resolution (``repro.pca.plan``) and the program's trace, lowering and
+    enqueue (``repro.pca.dispatch``) nest in it, all three with the
+    call's ``job`` id.
     """
     from repro.plan.planner import resolve_plan
 
-    hier = _hier_requested(topology, plan)
-    axes, m, pods = _agg_axes(mesh, data_axis, hier)
-    mem = resolve_membership(membership, m)
-    pl = resolve_plan(
-        plan, m=mem.m, d=samples.shape[-1], r=r,
-        n_iter=n_iter, backend=backend, topology=topology,
-        polar=polar, orth=orth, comm_bits=comm_bits, membership=mem,
-        pods=pods,
-    )
+    job = next(_JOB_IDS)
+    with jax.profiler.TraceAnnotation("repro.pca", job=job):
+        hier = _hier_requested(topology, plan)
+        axes, m, pods = _agg_axes(mesh, data_axis, hier)
+        mem = resolve_membership(membership, m)
+        with jax.profiler.TraceAnnotation("repro.pca.plan", job=job):
+            pl = resolve_plan(
+                plan, m=mem.m, d=samples.shape[-1], r=r,
+                n_iter=n_iter, backend=backend, topology=topology,
+                polar=polar, orth=orth, comm_bits=comm_bits, membership=mem,
+                pods=pods,
+            )
 
-    def shard_fn(x_shard: jax.Array) -> jax.Array:
-        v = _local_pca_basis(
-            x_shard, r, solver=solver, iters=iters, backend=pl.backend
-        )
-        out = procrustes_average_collective(
-            v, axis_name=data_axis, n_iter=n_iter, plan=pl, membership=mem,
-            pod_axis=POD_AXIS if hier else None,
-        )
-        return out[None]  # keep a sharded leading axis; identical on every shard
+        def shard_fn(x_shard: jax.Array) -> jax.Array:
+            v = _local_pca_basis(
+                x_shard, r, solver=solver, iters=iters, backend=pl.backend
+            )
+            out = procrustes_average_collective(
+                v, axis_name=data_axis, n_iter=n_iter, plan=pl, membership=mem,
+                pod_axis=POD_AXIS if hier else None,
+            )
+            return out[None]  # keep a sharded leading axis; identical on every shard
 
-    spec_in = P(axes, *(None,) * (samples.ndim - 1))
-    fn = jax.jit(
-        jax.shard_map(
-            shard_fn, mesh=mesh, in_specs=spec_in,
-            out_specs=P(axes, None, None), check_vma=False
+        spec_in = P(axes, *(None,) * (samples.ndim - 1))
+        fn = jax.jit(
+            jax.shard_map(
+                shard_fn, mesh=mesh, in_specs=spec_in,
+                out_specs=P(axes, None, None), check_vma=False
+            )
         )
-    )
-    stacked = fn(samples)
-    return stacked[0]
+        with jax.profiler.TraceAnnotation("repro.pca.dispatch", job=job):
+            return fn(samples)[0]
 
 
 def distributed_pca_from_covs(

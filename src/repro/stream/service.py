@@ -47,11 +47,19 @@ cadence tick.  A recovery waits for the cadence: the rejoiner's frozen
 accumulator is valid, merely stale, and re-enters by Procrustes-aligning
 to the served basis like any other shard.
 
-Staleness/drift/refresh metrics live in ``stats``.  Design: DESIGN.md §10.
+Staleness/drift/refresh metrics live in ``stats``, with the count of
+queries and of the blocking device reads the service made (refresh's two,
+drift's one) and the host seconds spent in those reads.  Each host step
+is a profiler span (``repro.service.observe``, ``.update``, ``.refresh``
+with its ``.wait_count``, ``.dispatch`` and ``.wait_jump``, ``.project``,
+``.drift``) carrying its ``step``, ``refresh`` or ``query`` id.  Design:
+DESIGN.md §10.
 """
 
 from __future__ import annotations
 
+import threading
+import time
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -223,6 +231,11 @@ class SubspaceService:
         self._last_drift: Optional[float] = None
         self._last_jump: Optional[float] = None
         self._refresh_cache: Dict[Any, Any] = {}
+        # ``project`` runs on callers' threads: its count takes the lock.
+        self._query_lock = threading.Lock()
+        self._queries = 0
+        self._syncs = 0
+        self._sync_wait_s = 0.0
 
     # -- ingest ------------------------------------------------------------
 
@@ -234,20 +247,24 @@ class SubspaceService:
         update program, so feed fixed-size chunks in steady state.  Dead
         shards' rows are ignored (their accumulators stay frozen).
         """
-        if isinstance(batches, (list, tuple)):
-            batches = jnp.stack([jnp.asarray(b) for b in batches])
-        batches = jnp.asarray(batches)
-        if batches.ndim != 3 or batches.shape[0] != self.m \
-                or batches.shape[2] != self.d:
-            raise ValueError(
-                f"expected (m={self.m}, n_k, d={self.d}) per-shard chunks, "
-                f"got {batches.shape}"
-            )
-        alive = jnp.asarray(self._mem.active)
-        self._state = _update_all(self._state, batches, alive)
-        self._step += 1
-        if self._refresh_due():
-            self.refresh()
+        step = self._step + 1
+        with jax.profiler.TraceAnnotation("repro.service.observe", step=step):
+            if isinstance(batches, (list, tuple)):
+                batches = jnp.stack([jnp.asarray(b) for b in batches])
+            batches = jnp.asarray(batches)
+            if batches.ndim != 3 or batches.shape[0] != self.m \
+                    or batches.shape[2] != self.d:
+                raise ValueError(
+                    f"expected (m={self.m}, n_k, d={self.d}) per-shard "
+                    f"chunks, got {batches.shape}"
+                )
+            with jax.profiler.TraceAnnotation("repro.service.update",
+                                              step=step):
+                alive = jnp.asarray(self._mem.active)
+                self._state = _update_all(self._state, batches, alive)
+            self._step = step
+            if self._refresh_due():
+                self.refresh()
         return self
 
     def _refresh_due(self) -> bool:
@@ -305,24 +322,45 @@ class SubspaceService:
         self._refresh_cache[key] = fn
         return fn
 
+    def _sync(self, read):
+        """Run ``read``, a blocking device read, and count its wait."""
+        t0 = time.perf_counter()
+        try:
+            return read()
+        finally:
+            self._syncs += 1
+            self._sync_wait_s += time.perf_counter() - t0
+
     def refresh(self) -> jax.Array:
         """Run one aggregation round now and swap the served basis."""
-        if float(jnp.sum(self._state["count"])) == 0:
-            raise ValueError("refresh before any data: observe() first")
-        covs = _safe_covs(self._state)
-        prev = self._buffers[self._front]
-        if prev is None:
-            stacked = self.refresh_fn(with_ref=False)(covs)
-        else:
-            stacked = self.refresh_fn(with_ref=True)(covs, prev)
-        new = stacked[self._mem.first_active]
-        if prev is not None:
-            self._last_jump = float(basis_jump(prev, new))
-        back = 1 - self._front
-        self._buffers[back] = new
-        self._front = back  # swap only after the collective returned
-        self._refreshes += 1
-        self._last_refresh_step = self._step
+        n = self._refreshes
+        with jax.profiler.TraceAnnotation("repro.service.refresh",
+                                          step=self._step, refresh=n):
+            with jax.profiler.TraceAnnotation(
+                    "repro.service.refresh.wait_count", refresh=n):
+                rows = self._sync(
+                    lambda: float(jnp.sum(self._state["count"])))
+            if rows == 0:
+                raise ValueError("refresh before any data: observe() first")
+            prev = self._buffers[self._front]
+            with jax.profiler.TraceAnnotation(
+                    "repro.service.refresh.dispatch", refresh=n):
+                covs = _safe_covs(self._state)
+                if prev is None:
+                    stacked = self.refresh_fn(with_ref=False)(covs)
+                else:
+                    stacked = self.refresh_fn(with_ref=True)(covs, prev)
+                new = stacked[self._mem.first_active]
+            if prev is not None:
+                with jax.profiler.TraceAnnotation(
+                        "repro.service.refresh.wait_jump", refresh=n):
+                    self._last_jump = self._sync(
+                        lambda: float(basis_jump(prev, new)))
+            back = 1 - self._front
+            self._buffers[back] = new
+            self._front = back  # swap only after the collective returned
+            self._refreshes += 1
+            self._last_refresh_step = self._step
         return new
 
     # -- elastic membership ------------------------------------------------
@@ -359,12 +397,18 @@ class SubspaceService:
 
     def project(self, queries: jax.Array) -> jax.Array:
         """Project (batch, d) query rows onto the served basis -> (batch, r)."""
-        v = self._buffers[self._front]  # single front read: no torn swap
-        if v is None:
-            raise RuntimeError(
-                "no basis served yet: observe() some data (or refresh()) first"
-            )
-        return _project_jit(queries, v)
+        with self._query_lock:
+            query = self._queries
+            self._queries += 1
+        with jax.profiler.TraceAnnotation("repro.service.project",
+                                          query=query):
+            v = self._buffers[self._front]  # single front read: no torn swap
+            if v is None:
+                raise RuntimeError(
+                    "no basis served yet: observe() some data (or refresh()) "
+                    "first"
+                )
+            return _project_jit(queries, v)
 
     @property
     def query_fn(self):
@@ -382,11 +426,14 @@ class SubspaceService:
         v = self._buffers[self._front]
         if v is None:
             raise RuntimeError("no basis served yet; drift is undefined")
-        covs = _safe_covs(self._state)
-        cbar = _mean_cov(
-            covs, self._state["count"], jnp.asarray(self._mem.active)
-        )
-        self._last_drift = float(_drift_metric(cbar, v.astype(cbar.dtype)))
+        with jax.profiler.TraceAnnotation("repro.service.drift",
+                                          step=self._step):
+            covs = _safe_covs(self._state)
+            cbar = _mean_cov(
+                covs, self._state["count"], jnp.asarray(self._mem.active)
+            )
+            self._last_drift = self._sync(
+                lambda: float(_drift_metric(cbar, v.astype(cbar.dtype))))
         return self._last_drift
 
     @property
@@ -404,11 +451,15 @@ class SubspaceService:
 
     @property
     def stats(self) -> Dict[str, Any]:
-        """Service health: staleness / drift / refresh counters / plan."""
+        """Service health: staleness / drift / refresh, query and
+        blocking-read counters / plan."""
         return {
             "step": self._step,
             "rows_seen": int(jnp.sum(self._state["count"])),
             "refreshes": self._refreshes,
+            "queries": self._queries,
+            "syncs": self._syncs,
+            "sync_wait_s": self._sync_wait_s,
             "staleness": self._step - self._last_refresh_step,
             "cadence": self.cadence,
             "drift": self._last_drift,
