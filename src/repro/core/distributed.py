@@ -47,6 +47,7 @@ them for end-to-end use.
 
 from __future__ import annotations
 
+import functools
 import itertools
 
 import jax
@@ -372,6 +373,59 @@ def _agg_axes(mesh, data_axis: str, hier: bool):
     return (POD_AXIS, data_axis), pods * mesh.shape[data_axis], pods
 
 
+@functools.lru_cache(maxsize=32)
+def _pca_program(
+    mesh: jax.sharding.Mesh,
+    data_axis: str,
+    hier: bool,
+    r: int,
+    n_iter: int,
+    solver: str,
+    iters: int,
+    pl,
+    mem: Membership,
+    ndim: int,
+    from_covs: bool = False,
+    with_ref: bool = False,
+):
+    """The jitted mesh program of ``distributed_pca`` (rows) or
+    ``distributed_pca_from_covs`` (``from_covs``; ``with_ref`` when the
+    caller passes a reference), built once per set of static inputs.
+
+    The arguments are everything the traced function closes over, so a
+    repeated call reuses one ``jax.jit`` object and JAX dispatches it
+    without tracing or lowering again.  Shapes, dtypes and shardings stay
+    out of the key: ``jax.jit`` keys its executables on them itself.
+    """
+    axes, _, _ = _agg_axes(mesh, data_axis, hier)
+
+    def shard_fn(x_shard: jax.Array, *ref: jax.Array) -> jax.Array:
+        if from_covs:
+            # x_shard: (m_local, d, d); m_local == 1 when m == mesh size.
+            v, _ = local_eigenbasis(
+                jnp.mean(x_shard, axis=0), r, method=solver, iters=iters
+            )
+        else:
+            v = _local_pca_basis(
+                x_shard, r, solver=solver, iters=iters, backend=pl.backend
+            )
+        out = procrustes_average_collective(
+            v, axis_name=data_axis, n_iter=n_iter,
+            ref=ref[0] if with_ref else None, plan=pl, membership=mem,
+            pod_axis=POD_AXIS if hier else None,
+        )
+        return out[None]  # keep a sharded leading axis; identical on every shard
+
+    spec_in = P(axes, *(None,) * (ndim - 1))
+    return jax.jit(
+        jax.shard_map(
+            shard_fn, mesh=mesh,
+            in_specs=(spec_in, P(None, None)) if with_ref else (spec_in,),
+            out_specs=P(axes, None, None), check_vma=False,
+        )
+    )
+
+
 def distributed_pca(
     samples: jax.Array,
     mesh: jax.sharding.Mesh,
@@ -413,17 +467,24 @@ def distributed_pca(
     then describes all pods*local machines in that order.  Returns the
     (d, r) estimate.
 
+    The jitted mesh program is built once per set of static arguments
+    (mesh, ``data_axis``, resolved plan and membership, ``r``, ``n_iter``,
+    ``solver``, ``iters``, ``samples.ndim``; ``_pca_program``), so a
+    repeated call dispatches it without tracing or lowering again.
+
     The call's host time is the profiler span ``repro.pca``; plan
-    resolution (``repro.pca.plan``) and the program's trace, lowering and
-    enqueue (``repro.pca.dispatch``) nest in it, all three with the
-    call's ``job`` id.
+    resolution (``repro.pca.plan``) and the program's enqueue
+    (``repro.pca.dispatch``) nest in it, all three with the call's ``job``
+    id.  ``repro.pca.dispatch`` also carries ``built``: 1 when this call
+    built the program, whose first call traces, lowers and fetches the
+    executable inside the span; 0 when it reused one.
     """
     from repro.plan.planner import resolve_plan
 
     job = next(_JOB_IDS)
     with jax.profiler.TraceAnnotation("repro.pca", job=job):
         hier = _hier_requested(topology, plan)
-        axes, m, pods = _agg_axes(mesh, data_axis, hier)
+        _, m, pods = _agg_axes(mesh, data_axis, hier)
         mem = resolve_membership(membership, m)
         with jax.profiler.TraceAnnotation("repro.pca.plan", job=job):
             pl = resolve_plan(
@@ -432,25 +493,15 @@ def distributed_pca(
                 polar=polar, orth=orth, comm_bits=comm_bits, membership=mem,
                 pods=pods,
             )
-
-        def shard_fn(x_shard: jax.Array) -> jax.Array:
-            v = _local_pca_basis(
-                x_shard, r, solver=solver, iters=iters, backend=pl.backend
-            )
-            out = procrustes_average_collective(
-                v, axis_name=data_axis, n_iter=n_iter, plan=pl, membership=mem,
-                pod_axis=POD_AXIS if hier else None,
-            )
-            return out[None]  # keep a sharded leading axis; identical on every shard
-
-        spec_in = P(axes, *(None,) * (samples.ndim - 1))
-        fn = jax.jit(
-            jax.shard_map(
-                shard_fn, mesh=mesh, in_specs=spec_in,
-                out_specs=P(axes, None, None), check_vma=False
-            )
+        misses = _pca_program.cache_info().misses
+        fn = _pca_program(
+            mesh, data_axis, hier, r, n_iter, solver, iters, pl, mem,
+            samples.ndim,
         )
-        with jax.profiler.TraceAnnotation("repro.pca.dispatch", job=job):
+        built = int(_pca_program.cache_info().misses > misses)
+        with jax.profiler.TraceAnnotation(
+            "repro.pca.dispatch", job=job, built=built
+        ):
             return fn(samples)[0]
 
 
@@ -478,7 +529,8 @@ def distributed_pca_from_covs(
     useful when the local matrices are not covariances (e.g. quadratic
     sensing's D_N, HOPE proximity matrices).  ``plan`` / ``comm_bits`` /
     ``membership`` / ``topology="hier"`` as in ``distributed_pca``
-    (resolved once at the driver level).
+    (resolved once at the driver level); its program is built once and
+    reused the same way, one for calls with ``ref`` and one without.
 
     ``ref`` optionally supplies the (d, r) alignment reference instead of
     the first active shard's basis — the streaming service passes its
@@ -491,7 +543,7 @@ def distributed_pca_from_covs(
     from repro.plan.planner import resolve_plan
 
     hier = _hier_requested(topology, plan)
-    axes, m, pods = _agg_axes(mesh, data_axis, hier)
+    _, m, pods = _agg_axes(mesh, data_axis, hier)
     mem = resolve_membership(membership, m)
     pl = resolve_plan(
         plan, m=mem.m, d=covs.shape[-1], r=r,
@@ -500,34 +552,9 @@ def distributed_pca_from_covs(
         pods=pods, ref_broadcast=(ref is None),
     )
 
-    def shard_fn(cov_shard: jax.Array, ref_arg: jax.Array | None) -> jax.Array:
-        # cov_shard: (m_local, d, d); m_local == 1 when m == mesh size.
-        cov = jnp.mean(cov_shard, axis=0)
-        v, _ = local_eigenbasis(cov, r, method=solver, iters=iters)
-        out = procrustes_average_collective(
-            v, axis_name=data_axis, n_iter=n_iter, ref=ref_arg, plan=pl,
-            membership=mem, pod_axis=POD_AXIS if hier else None,
-        )
-        return out[None]
-
-    if ref is None:
-        fn = jax.jit(
-            jax.shard_map(
-                lambda c: shard_fn(c, None),
-                mesh=mesh,
-                in_specs=P(axes, None, None),
-                out_specs=P(axes, None, None),
-                check_vma=False,
-            )
-        )
-        return fn(covs)[0]
-    fn = jax.jit(
-        jax.shard_map(
-            shard_fn,
-            mesh=mesh,
-            in_specs=(P(axes, None, None), P(None, None)),
-            out_specs=P(axes, None, None),
-            check_vma=False,
-        )
+    with_ref = ref is not None
+    fn = _pca_program(
+        mesh, data_axis, hier, r, n_iter, solver, iters, pl, mem, covs.ndim,
+        from_covs=True, with_ref=with_ref,
     )
-    return fn(covs, ref)[0]
+    return (fn(covs, ref) if with_ref else fn(covs))[0]

@@ -23,7 +23,7 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core.distributed import distributed_pca
+from repro.core.distributed import _pca_program, distributed_pca
 from repro.data import synthetic as syn
 from repro.launch.mesh import make_aggregation_mesh
 from repro.stream.service import SubspaceService
@@ -87,6 +87,8 @@ def run(tmp_path_factory):
     chunks = _rows(STEPS * CHUNK, seed=1).reshape(STEPS, CHUNK, D)
     queries = _rows(QUERIES * 8, seed=2).reshape(QUERIES, 8, D)
     plain = _workload(mesh, rows, chunks, queries)
+    # The traced run's first PCA call then builds its program again.
+    _pca_program.cache_clear()
     log_dir = str(tmp_path_factory.mktemp("trace"))
     jax.profiler.start_trace(log_dir)
     try:
@@ -122,6 +124,12 @@ def test_pca_steps_nest_in_their_call_under_one_job_id(run, child):
 def test_each_pca_call_gets_its_own_job_id(run):
     jobs = [s.ids["job"] for s in _named(run.spans, "repro.pca")]
     assert jobs[1] == jobs[0] + 1
+
+
+def test_pca_dispatch_says_whether_it_built_the_program(run):
+    # The first call builds the program, the second reuses it.
+    built = [s.ids["built"] for s in _named(run.spans, "repro.pca.dispatch")]
+    assert built == [1, 0]
 
 
 # (span, the span that holds it, the id they share)
