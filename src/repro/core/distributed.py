@@ -73,7 +73,8 @@ from repro.core.covariance import empirical_covariance
 from repro.core.eigenspace import refinement_rounds
 from repro.core.orthonorm import orthonormalize, resolve_orth
 from repro.core.precision import highest_precision
-from repro.core.subspace import local_eigenbasis
+from repro.core.rowop import check_standardize, row_operator
+from repro.core.subspace import local_eigenbasis, subspace_iteration
 
 __all__ = [
     "broadcast_from",
@@ -329,6 +330,41 @@ def sign_average_collective(v_local: jax.Array, *, axis_name: str) -> jax.Array:
     return vbar / jnp.linalg.norm(vbar)
 
 
+def _device_bytes(device) -> int | None:
+    """The device's memory in bytes, where its runtime reports it (a CPU
+    reports none; a described, unattached chip raises)."""
+    try:
+        stats = device.memory_stats()
+    except jax.errors.JaxRuntimeError:
+        return None
+    return int(stats["bytes_limit"]) if stats else None
+
+
+def _local_form(d: int, device_bytes: int | None) -> str:
+    """The local solve's form for rows of width d: ``"rows"``
+    (``repro.core.rowop``, no (d, d) array) where the (d, d) float32
+    covariance would not fit in ``device_bytes``, else ``"cov"`` (form
+    XᵀX/n, then solve on it)."""
+    if device_bytes is not None and 4 * d * d > device_bytes:
+        return "rows"
+    return "cov"
+
+
+def _shard_form(samples, mesh) -> str:
+    """``_local_form`` of ``samples``' rows on the mesh's devices."""
+    return _local_form(samples.shape[-1], _device_bytes(mesh.devices.flat[0]))
+
+
+def _check_local_solve(form: str, solver: str, standardize) -> None:
+    check_standardize(standardize)
+    if form == "rows" and solver != "subspace":
+        raise ValueError(
+            f"solver={solver!r} needs the (d, d) covariance, and these rows "
+            "take the row form (a (d, d) float32 matrix would not fit on "
+            "the device); use solver='subspace'"
+        )
+
+
 def _local_pca_basis(
     x_shard: jax.Array,
     r: int,
@@ -336,7 +372,20 @@ def _local_pca_basis(
     solver: str,
     iters: int,
     backend: str,
+    form: str = "cov",
+    standardize: str | None = None,
 ) -> jax.Array:
+    """One shard's leading (d, r) basis, in the ``form`` ``_local_form``
+    chose: the row form iterates on Xᵀ(XQ)/n, the covariance form on
+    XᵀX/n.  ``standardize`` maps the raw rows to X first (folded into the
+    products in the row form)."""
+    _check_local_solve(form, solver, standardize)
+    if form == "rows":
+        v, _ = subspace_iteration(row_operator(x_shard, standardize), r,
+                                  iters=iters)
+        return v
+    if standardize is not None:
+        x_shard = row_operator(x_shard, standardize).standardized()
     cov = empirical_covariance(x_shard, backend=backend)
     v, _ = local_eigenbasis(cov, r, method=solver, iters=iters)
     return v
@@ -387,10 +436,14 @@ def _pca_program(
     ndim: int,
     from_covs: bool = False,
     with_ref: bool = False,
+    form: str = "cov",
+    standardize: str | None = None,
 ):
     """The jitted mesh program of ``distributed_pca`` (rows) or
     ``distributed_pca_from_covs`` (``from_covs``; ``with_ref`` when the
     caller passes a reference), built once per set of static inputs.
+    ``form`` and ``standardize`` are the rows' local solve
+    (``_local_pca_basis``).
 
     The arguments are everything the traced function closes over, so a
     repeated call reuses one ``jax.jit`` object and JAX dispatches it
@@ -407,7 +460,8 @@ def _pca_program(
             )
         else:
             v = _local_pca_basis(
-                x_shard, r, solver=solver, iters=iters, backend=pl.backend
+                x_shard, r, solver=solver, iters=iters, backend=pl.backend,
+                form=form, standardize=standardize,
             )
         out = procrustes_average_collective(
             v, axis_name=data_axis, n_iter=n_iter,
@@ -442,17 +496,33 @@ def distributed_pca(
     comm_bits=None,
     plan=None,
     membership: Membership | None = None,
+    standardize: str | None = None,
 ) -> jax.Array:
     """End-to-end one-shot distributed PCA on a mesh.
 
     ``samples`` (N, d) are sharded along the leading axis over ``data_axis``;
-    each shard forms its local covariance, local top-r basis, and the mesh
-    runs the Procrustes-fixed average.  ``backend`` selects the compute
-    path — ``"pallas"`` kernels both the shard-local covariance stage and
-    the aggregation (see module docstring) — ``polar`` the rotation
-    method, ``orth`` the per-round orthonormalization, ``topology``
-    the communication schedule the aggregation runs over, and
-    ``comm_bits`` the wire precision of its payloads.
+    each shard finds its local top-r basis, and the mesh runs the
+    Procrustes-fixed average.
+
+    The local solve takes one of two forms, chosen from the shapes alone
+    (``_local_form``).  The covariance form forms XᵢᵀXᵢ/n and solves on it.
+    The row form (``repro.core.rowop``) iterates on Xᵢᵀ(XᵢQ)/n and never
+    holds a (d, d) array; rows whose (d, d) float32 covariance would not
+    fit on the device take it (at UK Biobank's d=147,604 it is 87 GB).
+    The row form runs ``solver="subspace"`` only: ``"eigh"`` raises there.
+
+    ``standardize`` states how the raw rows map to the estimator's X:
+    ``None`` (they are X) or ``"patterson"`` (int8 genotype counts
+    standardized per shard by Patterson et al. 2006 eq. 3,
+    ``repro.data.genotypes``; in the row form the map is folded into the
+    products, so no floating (n, d) copy is made).
+
+    ``backend`` selects the compute path — ``"pallas"`` kernels both the
+    shard-local covariance stage and the aggregation (see module
+    docstring) — ``polar`` the rotation method, ``orth`` the per-round
+    orthonormalization, ``topology`` the communication schedule the
+    aggregation runs over, and ``comm_bits`` the wire precision of its
+    payloads.
     ``plan=None|"auto"|Plan`` resolves all five through the execution
     planner (``repro.plan``): the plan is resolved once here at the
     driver level — so a planned ``backend`` also routes the shard-local
@@ -469,15 +539,17 @@ def distributed_pca(
 
     The jitted mesh program is built once per set of static arguments
     (mesh, ``data_axis``, resolved plan and membership, ``r``, ``n_iter``,
-    ``solver``, ``iters``, ``samples.ndim``; ``_pca_program``), so a
-    repeated call dispatches it without tracing or lowering again.
+    ``solver``, ``iters``, ``samples.ndim``, the local form and
+    ``standardize``; ``_pca_program``), so a repeated call dispatches it
+    without tracing or lowering again.
 
     The call's host time is the profiler span ``repro.pca``; plan
     resolution (``repro.pca.plan``) and the program's enqueue
     (``repro.pca.dispatch``) nest in it, all three with the call's ``job``
     id.  ``repro.pca.dispatch`` also carries ``built``: 1 when this call
     built the program, whose first call traces, lowers and fetches the
-    executable inside the span; 0 when it reused one.
+    executable inside the span; 0 when it reused one; and ``form``, the
+    local solve's form (``cov`` or ``rows``).
     """
     from repro.plan.planner import resolve_plan
 
@@ -485,6 +557,7 @@ def distributed_pca(
     with jax.profiler.TraceAnnotation("repro.pca", job=job):
         hier = _hier_requested(topology, plan)
         _, m, pods = _agg_axes(mesh, data_axis, hier)
+        form = _shard_form(samples, mesh)
         mem = resolve_membership(membership, m)
         with jax.profiler.TraceAnnotation("repro.pca.plan", job=job):
             pl = resolve_plan(
@@ -496,11 +569,11 @@ def distributed_pca(
         misses = _pca_program.cache_info().misses
         fn = _pca_program(
             mesh, data_axis, hier, r, n_iter, solver, iters, pl, mem,
-            samples.ndim,
+            samples.ndim, form=form, standardize=standardize,
         )
         built = int(_pca_program.cache_info().misses > misses)
         with jax.profiler.TraceAnnotation(
-            "repro.pca.dispatch", job=job, built=built
+            "repro.pca.dispatch", job=job, built=built, form=form
         ):
             return fn(samples)[0]
 

@@ -189,7 +189,7 @@ def elastic_pca(
     with each group's call: telescoping within a group, a clean zero
     residual whenever membership changes.
     """
-    from repro.core.distributed import _local_pca_basis
+    from repro.core.distributed import _local_pca_basis, _shard_form
     from repro.plan.planner import resolve_plan
 
     m = mesh.shape[data_axis]
@@ -231,13 +231,15 @@ def elastic_pca(
         membership=mem0, **pins,
     )
 
-    # Local stage, once: each shard's covariance + top-r basis, stacked
-    # sharded along the axis.  The planned backend routes it, like the
-    # driver in ``core.distributed``.
+    # Local stage, once: each shard's top-r basis, in the form its shape
+    # takes, stacked sharded along the axis.  The planned backend routes
+    # it, like the driver in ``core.distributed``.
+    form = _shard_form(samples, mesh)
     local_fn = jax.jit(
         jax.shard_map(
             lambda x: _local_pca_basis(
-                x, r, solver=solver, iters=iters, backend=pl.backend
+                x, r, solver=solver, iters=iters, backend=pl.backend,
+                form=form,
             )[None],
             mesh=mesh,
             in_specs=P(data_axis, *(None,) * (samples.ndim - 1)),

@@ -23,6 +23,8 @@ from repro.kernels import covariance, procrustes_align
 
 D, R, M, N = 8192, 128, 8, 65536
 RING_M, RING_R = 4, 64
+# ukb_genotype_pca: 18,510 participants of one site, 147,604 markers, 40 PCs.
+GENO_N, GENO_D, GENO_R = 18510, 147604, 40
 
 
 @pytest.fixture(scope="module")
@@ -122,3 +124,28 @@ def test_fused_ring_collective_compiles_on_four_chips(topo, monkeypatch):
     vs = jax.ShapeDtypeStruct((RING_M, D, RING_R), jnp.float32,
                               sharding=NamedSharding(mesh, P("data")))
     _compiles_kernel(fn, vs)
+
+
+def test_genotype_pca_compiles_at_uk_biobank_widths(topo, monkeypatch):
+    """``distributed_pca`` on one site's int8 genotypes as the one-chip
+    ``gpca_ukb_site`` cell runs it (backend ``auto`` is the kernels on the
+    chip): the plan compiles at d=147,604, r=40, and the row form's
+    program holds no (d, d) array and no floating (n, d) one."""
+    import repro.core.distributed as distributed
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "interpret_default", lambda: False)
+    # A described chip has no runtime to report its memory: a v5e's 16 GiB.
+    monkeypatch.setattr(distributed, "_device_bytes", lambda dev: 16 * 2**30)
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+    counts = jax.ShapeDtypeStruct((GENO_N, GENO_D), jnp.int8,
+                                  sharding=NamedSharding(mesh, P("data")))
+    text = jax.jit(lambda c: distributed.distributed_pca(
+        c, mesh, GENO_R, standardize="patterson", solver="subspace",
+        iters=30, n_iter=2, backend="pallas")).lower(counts).compile(
+    ).as_text()
+    assert "tpu_custom_call" in text
+    assert f"s8[{GENO_N},{GENO_D}]" in text
+    for dtype in ("f32", "bf16", "f16"):
+        for dims in ((GENO_D, GENO_D), (GENO_N, GENO_D), (GENO_D, GENO_N)):
+            assert f"{dtype}[{dims[0]},{dims[1]}]" not in text

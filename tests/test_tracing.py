@@ -132,6 +132,12 @@ def test_pca_dispatch_says_whether_it_built_the_program(run):
     assert built == [1, 0]
 
 
+def test_pca_dispatch_names_the_local_form(run):
+    # 256 rows of width 32 on one device: the covariance form.
+    forms = [s.ids["form"] for s in _named(run.spans, "repro.pca.dispatch")]
+    assert forms == ["cov", "cov"]
+
+
 # (span, the span that holds it, the id they share)
 SERVICE_NESTING = [
     ("repro.service.update", "repro.service.observe", "step"),
