@@ -6,95 +6,156 @@ problem: stream (bn, bd) tiles of X through VMEM and accumulate f32
 (bd, bd) output tiles.
 
 Tiling:
-  grid = (d/bd, d/bd, n/bn); the last grid dim is sequential on TPU, so the
-  output tile accumulates across the n-loop.  Both operand tiles are VMEM
-  blocks of X; accumulation is f32 regardless of input dtype (bf16 inputs
-  hit the MXU natively).
+  grid = (T(T+1)/2, n/bn) for T = d/bd: the first axis enumerates only the
+  upper-triangle output tiles (i <= j), through two scalar-prefetched
+  tables t -> i and t -> j that the ``BlockSpec`` index maps read, so a
+  lower tile costs neither a DMA nor a grid step.  The last grid axis is
+  sequential on TPU, so the output tile accumulates across the n-loop,
+  128 rows per product.  Both operand tiles are VMEM blocks of X;
+  accumulation is f32 regardless of input dtype.  The wrapper mirrors the
+  upper triangle into the lower one with a select (the kernel never
+  writes the lower tiles).
 
-VMEM budget per step: 2 * bn*bd * sizeof(in) + bd*bd * 4 bytes
-  (128, 512) bf16 tiles -> 2*128*512*2 + 512*512*4 = 1.3 MiB  << 16 MiB.
-
-The symmetric upper/lower redundancy (out is symmetric) is deliberately kept:
-skipping lower tiles halves FLOPs but produces a non-contiguous write set;
-measured on the roofline it is compute-bound only for d > 4096, where the
-``symmetric=True`` flag enables the triangle-skip variant.
+Blocks come from the shape (``gram_blocks``): each grid step moves
+2·bn·bd·itemsize bytes for 2·bn·bd² FLOP, so a wider bd raises the work
+per byte and cuts the steps, while a (bd, bd) diagonal tile computes its
+lower half for nothing.  The VMEM a choice needs (double-buffered operand
+and output tiles, the product and the f32 operand copies) is stated by
+``gram_vmem_bytes`` and held under ``VMEM_BUDGET``; the ``pallas_call``
+asks for that much scoped VMEM.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["gram"]
+__all__ = [
+    "gram", "gram_blocks", "gram_vmem_bytes", "GramBlocks", "VMEM_BUDGET",
+]
+
+# Scoped VMEM the kernel may ask for: half of a v5e core's 128 MiB.
+VMEM_BUDGET = 64 * 2**20
+# The default blocks, from a sweep of bn in 128..1024 and bd in 512..2048
+# at a 65,536 x 8192 f32 shard on a TPU v5e (PERF.md): bd=1024 balances
+# the diagonal tiles' wasted half against the operand bytes, and a deeper
+# bn cuts the steps.
+_BN, _BD = 1024, 1024
+# Rows per product in the kernel body: a (bn, bd) tile is summed into the
+# output 128 rows at a time, so G's float32 sums run in the same order
+# whatever bn is (bit for bit; at bn=1024 also 4% faster than one product).
+_ROWS = 128
+# Room for Mosaic's own scratch on top of the stated buffers.
+_VMEM_HEADROOM = 4 * 2**20
 
 
-def _gram_kernel(x_i, x_j, out, *, triangle_skip: bool):
-    i, j, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+class GramBlocks(NamedTuple):
+    bn: int
+    bd: int
+    tiles: int  # output tiles launched: the upper triangle, T(T+1)/2
+    full_tiles: int  # output tiles of the full square, T²
+    vmem_bytes: int  # ``gram_vmem_bytes(bn, bd, itemsize)``
 
-    @pl.when(k == 0)
+
+def gram_vmem_bytes(bn: int, bd: int, itemsize: int) -> int:
+    """VMEM one grid step holds: two (bn, bd) operand tiles and the f32
+    (bd, bd) output tile, each double-buffered by the pipeline, plus an
+    f32 product and f32 copies of the operands (bounded by the tile)."""
+    return (2 * 2 * bn * bd * itemsize + 2 * bd * bd * 4
+            + bd * bd * 4 + 2 * bn * bd * 4)
+
+
+def gram_blocks(
+    n: int, d: int, itemsize: int, *, bn: int | None = None,
+    bd: int | None = None,
+) -> GramBlocks:
+    """Blocks of the Gram kernel for (n, d) rows of ``itemsize`` bytes.
+
+    ``bd`` defaults to 1024 and ``bn`` to 1024, halved while the step's
+    VMEM exceeds ``VMEM_BUDGET``; either is clamped to the (small) shape.
+    n and d are then padded up to multiples of the blocks.
+    """
+    bd = _BD if bd is None else bd
+    if bn is None:
+        bn = _BN
+        while gram_vmem_bytes(bn, bd, itemsize) > VMEM_BUDGET and bn > 8:
+            bn //= 2
+    bn = min(bn, max(8, n))
+    bd = min(bd, max(8, d))
+    t = -(-d // bd)
+    return GramBlocks(bn, bd, t * (t + 1) // 2, t * t,
+                      gram_vmem_bytes(bn, bd, itemsize))
+
+
+def _gram_kernel(ti, tj, x_i, x_j, out, *, rows: int):
+    del ti, tj  # consumed by the index maps
+
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         out[...] = jnp.zeros_like(out)
 
-    def _accum():
+    for s in range(x_i.shape[0] // rows):
+        r = pl.ds(s * rows, rows)
         out[...] += jnp.dot(
-            x_i[...].T.astype(jnp.float32),
-            x_j[...].astype(jnp.float32),
+            x_i[r, :].T.astype(jnp.float32),
+            x_j[r, :].astype(jnp.float32),
             preferred_element_type=jnp.float32,
         )
 
-    if triangle_skip:
-        # Only compute upper-triangle tiles (i <= j); mirror in the wrapper.
-        @pl.when(i <= j)
-        def _maybe():
-            _accum()
-    else:
-        _accum()
 
-
-@functools.partial(
-    jax.jit, static_argnames=("bn", "bd", "symmetric", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("bn", "bd", "interpret"))
 def gram(
     x: jax.Array,
     *,
-    bn: int = 128,
-    bd: int = 512,
-    symmetric: bool = False,
+    bn: int | None = None,
+    bd: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """``X^T X`` for x of shape (n, d); f32 output.
+    """``X^T X`` for x of shape (n, d); f32 output, exactly symmetric.
 
     Pads n and d up to the block sizes (zero rows/cols contribute nothing to
-    the Gram product, so padding is exact).
+    the Gram product, so padding is exact).  ``bn``/``bd`` override the
+    blocks ``gram_blocks`` picks from the shape.
     """
     n, d = x.shape
-    bn = min(bn, max(8, n))
-    bd = min(bd, max(8, d))
+    blocks = gram_blocks(n, d, x.dtype.itemsize, bn=bn, bd=bd)
+    bn, bd = blocks.bn, blocks.bd
     n_pad = (-n) % bn
     d_pad = (-d) % bd
     if n_pad or d_pad:
         x = jnp.pad(x, ((0, n_pad), (0, d_pad)))
     np_, dp = x.shape
-    grid = (dp // bd, dp // bd, np_ // bn)
+    ti, tj = np.triu_indices(dp // bd)
     out = pl.pallas_call(
-        functools.partial(_gram_kernel, triangle_skip=symmetric),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bn, bd), lambda i, j, k: (k, i)),
-            pl.BlockSpec((bn, bd), lambda i, j, k: (k, j)),
-        ],
-        out_specs=pl.BlockSpec((bd, bd), lambda i, j, k: (i, j)),
+        functools.partial(
+            _gram_kernel, rows=_ROWS if bn % _ROWS == 0 else bn),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(blocks.tiles, np_ // bn),
+            in_specs=[
+                pl.BlockSpec((bn, bd), lambda t, k, ti, tj: (k, ti[t])),
+                pl.BlockSpec((bn, bd), lambda t, k, ti, tj: (k, tj[t])),
+            ],
+            out_specs=pl.BlockSpec(
+                (bd, bd), lambda t, k, ti, tj: (ti[t], tj[t])),
+        ),
         out_shape=jax.ShapeDtypeStruct((dp, dp), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=blocks.vmem_bytes + _VMEM_HEADROOM,
+        ),
         interpret=interpret,
-    )(x, x)
+        name="gram",
+    )(jnp.asarray(ti, jnp.int32), jnp.asarray(tj, jnp.int32), x, x)
     out = out[:d, :d]
-    if symmetric:
-        # Mirror the strictly-upper triangle into the (uncomputed, zero)
-        # lower one.  Mask-free: two triangular selects XLA fuses in place,
-        # instead of materialising a dense (dp, dp) bool mask.  Trimming
-        # first keeps the mirror O(d^2) rather than O(dp^2).
-        out = jnp.triu(out) + jnp.triu(out, k=1).T
-    return out
+    # The lower tiles were never written: select the upper triangle and
+    # its transpose (a select, so whatever they hold never reaches G).
+    i = jax.lax.broadcasted_iota(jnp.int32, (d, d), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (d, d), 1)
+    return jnp.where(i <= j, out, out.T)

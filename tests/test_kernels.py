@@ -27,17 +27,74 @@ def test_gram_shapes(n, d, dtype):
     )
 
 
-@pytest.mark.parametrize("symmetric", [False, True])
-def test_gram_block_size_invariance(symmetric):
-    x = jax.random.normal(jax.random.PRNGKey(1), (192, 256))
+@pytest.mark.parametrize("n,d", [(192, 256), (300, 520)])
+def test_gram_block_size_invariance(n, d):
+    x = jax.random.normal(jax.random.PRNGKey(1), (n, d))
     outs = [
-        covariance.gram(x, bn=bn, bd=bd, symmetric=symmetric, interpret=True)
+        covariance.gram(x, bn=bn, bd=bd, interpret=True)
         for bn, bd in [(64, 64), (128, 128), (192, 256)]
     ]
     for o in outs[1:]:
         np.testing.assert_allclose(
             np.asarray(outs[0]), np.asarray(o), atol=1e-3
         )
+
+
+@pytest.mark.parametrize("bn", [256, 1024])
+def test_gram_sums_in_128_row_steps(bn):
+    """A deeper row block sums 128 rows at a time into the output, so G is
+    bit for bit the one 128-row blocks give."""
+    x = jax.random.normal(jax.random.PRNGKey(4), (1024, 384))
+    want = np.asarray(covariance.gram(x, bn=128, bd=128, interpret=True))
+    got = np.asarray(covariance.gram(x, bn=bn, bd=384, interpret=True))
+    assert np.array_equal(got, want)
+
+
+# Several tiles on each side, ragged in n and d, small forced blocks.
+TRIANGLE_CASES = [(300, 1030, 64, 256), (1000, 2100, 128, 512)]
+
+
+@pytest.mark.parametrize("n,d,bn,bd", TRIANGLE_CASES)
+def test_gram_triangle_grid(n, d, bn, bd):
+    x = jax.random.normal(jax.random.PRNGKey(2), (n, d))
+    got = covariance.gram(x, bn=bn, bd=bd, interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(ref.gram(x)), atol=1e-5 * n, rtol=1e-5
+    )
+
+
+@pytest.mark.parametrize(
+    "n,d,bn,bd", TRIANGLE_CASES + [(257, 129, None, None)]
+)
+def test_gram_exactly_symmetric(n, d, bn, bd):
+    """The lower triangle is the computed upper one, selected bit for bit
+    (the kernel never writes the lower tiles)."""
+    x = jax.random.normal(jax.random.PRNGKey(3), (n, d))
+    g = np.asarray(covariance.gram(x, bn=bn, bd=bd, interpret=True))
+    assert np.array_equal(g, g.T)
+    assert np.isfinite(g).all()
+
+
+@pytest.mark.parametrize(
+    "n,d,itemsize",
+    [(65536, 8192, 4), (65536, 8192, 2), (18510, 8192, 4), (300, 1030, 4),
+     (64, 64, 4), (8, 512, 2), (100_000, 30_000, 4)],
+)
+def test_gram_blocks_rule(n, d, itemsize):
+    b = covariance.gram_blocks(n, d, itemsize)
+    n_pad, d_pad = -(-n // b.bn) * b.bn, -(-d // b.bd) * b.bd
+    t = d_pad // b.bd
+    assert (b.tiles, b.full_tiles) == (t * (t + 1) // 2, t * t)
+    assert n_pad % b.bn == 0 and d_pad % b.bd == 0
+    assert n_pad - n < b.bn and d_pad - d < b.bd
+    assert b.vmem_bytes == covariance.gram_vmem_bytes(b.bn, b.bd, itemsize)
+    assert b.vmem_bytes <= covariance.VMEM_BUDGET
+
+
+def test_gram_blocks_paper_pca():
+    """At paper_pca's shard the kernel launches 36 of the 64 tiles."""
+    b = covariance.gram_blocks(65536, 8192, 4)
+    assert (b.bn, b.bd, b.tiles, b.full_tiles) == (1024, 1024, 36, 64)
 
 
 @settings(max_examples=15, deadline=None)
@@ -52,6 +109,40 @@ def test_gram_property(n, d, seed):
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(ref.gram(x)), atol=1e-3 * d
     )
+
+
+def _dot_precisions(jaxpr, inside=None, found=None):
+    """(pallas kernel name or None, precision) of every dot_general."""
+    found = [] if found is None else found
+    for e in jaxpr.eqns:
+        where = inside
+        if e.primitive.name == "pallas_call":
+            where = str(e.params["name"])
+        if e.primitive.name == "dot_general":
+            found.append((where, e.params["precision"]))
+        for p in e.params.values():
+            for sub in p if isinstance(p, (list, tuple)) else [p]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _dot_precisions(sub, where, found)
+    return found
+
+
+def test_gram_precision_on_pipeline_path():
+    """The local PCA traces the Gram kernel's product at the default
+    precision (one bf16 pass, f32 sums) and the solve's at HIGHEST."""
+    from repro.core import distributed
+
+    jx = jax.make_jaxpr(
+        lambda x: distributed._local_pca_basis(
+            x, 4, solver="subspace", iters=2, backend="pallas")
+    )(jax.ShapeDtypeStruct((256, 512), jnp.float32))
+    dots = _dot_precisions(jx.jaxpr)
+    gram = [p for w, p in dots if w == "gram"]
+    assert gram and all(p is None for p in gram)
+    highest = (jax.lax.Precision.HIGHEST,) * 2
+    solve = [p for w, p in dots if w != "gram"]
+    assert solve and all(p == highest for p in solve)
 
 
 # --------------------------------------------------- procrustes stages ----

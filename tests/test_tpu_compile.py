@@ -61,6 +61,20 @@ def test_gram_compiles(shape):
     )
 
 
+@pytest.mark.parametrize(
+    "bn,bd",
+    [(bn, bd) for bn in (128, 256, 512, 1024) for bd in (512, 1024, 2048)
+     if bn * bd <= 2**20],
+)
+def test_gram_candidate_blocks_compile(shape, bn, bd):
+    """Every block pair the on-chip sweep timed (PERF.md) fits the VMEM it
+    asks for at the paper_pca shard."""
+    _compiles_kernel(
+        lambda x: covariance.gram(x, bn=bn, bd=bd, interpret=False),
+        shape((N, D)),
+    )
+
+
 def test_batched_gram_polar_compiles(shape):
     _compiles_kernel(
         lambda v, ref: procrustes_align.batched_gram_polar(
