@@ -47,6 +47,8 @@ them for end-to-end use.
 
 from __future__ import annotations
 
+import contextlib
+import dataclasses
 import functools
 import itertools
 
@@ -391,18 +393,6 @@ def _local_pca_basis(
     return v
 
 
-def _hier_requested(topology, plan) -> bool:
-    """True when the caller asked for the hierarchical schedule (an
-    explicit ``topology="hier"`` pin or a resolved hier ``Plan``) — the
-    driver then aggregates over *both* mesh axes of the (pod, local)
-    pair instead of ``data_axis`` alone."""
-    from repro.plan.planner import Plan
-
-    return topology == "hier" or (
-        isinstance(plan, Plan) and plan.topology == "hier"
-    )
-
-
 def _agg_axes(mesh, data_axis: str, hier: bool):
     """(shard axes, machine count, pod count) of the aggregation.
 
@@ -422,28 +412,82 @@ def _agg_axes(mesh, data_axis: str, hier: bool):
     return (POD_AXIS, data_axis), pods * mesh.shape[data_axis], pods
 
 
+def _resolve_job(mesh, data_axis: str, d: int, r: int, *, plan,
+                 topology=None, membership=None, job=None, **knobs):
+    """The prologue every entry point shares: the aggregation's mesh axes,
+    its membership, and the plan resolved at that membership.
+
+    The aggregation spans both axes of the (pod, local) pair (``hier``)
+    when the caller asked for the hierarchical schedule: a
+    ``topology="hier"`` pin or a resolved hier ``Plan``.  ``knobs`` go to
+    ``resolve_plan`` as given.  With a ``job`` id the plan resolution is
+    the profiler span ``repro.pca.plan``.  Returns (hier, pods,
+    membership, plan).
+    """
+    from repro.plan.planner import Plan, resolve_plan
+
+    hier = topology == "hier" or (
+        isinstance(plan, Plan) and plan.topology == "hier")
+    _, m, pods = _agg_axes(mesh, data_axis, hier)
+    mem = resolve_membership(membership, m)
+    span = (contextlib.nullcontext() if job is None else
+            jax.profiler.TraceAnnotation("repro.pca.plan", job=job))
+    with span:
+        pl = resolve_plan(plan, m=mem.m, d=d, r=r, topology=topology,
+                          membership=mem, pods=pods, **knobs)
+    return hier, pods, mem, pl
+
+
+@dataclasses.dataclass(frozen=True)
+class _LocalStage:
+    """The shard-local stage of a mesh program: one shard's (d, r) basis.
+
+    ``kind="rows"``: raw rows through ``_local_pca_basis`` (``backend``,
+    ``form`` and ``standardize`` as there); ``kind="covs"``: stacked
+    (m_local, d, d) local matrices, averaged, then solved.
+    """
+
+    kind: str
+    r: int
+    solver: str
+    iters: int
+    backend: str | None = None
+    form: str = "cov"
+    standardize: str | None = None
+
+    def __call__(self, x_shard: jax.Array) -> jax.Array:
+        if self.kind == "covs":
+            # x_shard: (m_local, d, d); m_local == 1 when m == mesh size.
+            v, _ = local_eigenbasis(jnp.mean(x_shard, axis=0), self.r,
+                                    method=self.solver, iters=self.iters)
+            return v
+        return _local_pca_basis(
+            x_shard, self.r, solver=self.solver, iters=self.iters,
+            backend=self.backend, form=self.form,
+            standardize=self.standardize,
+        )
+
+
 @functools.lru_cache(maxsize=32)
-def _pca_program(
+def _mesh_program(
     mesh: jax.sharding.Mesh,
     data_axis: str,
     hier: bool,
-    r: int,
-    n_iter: int,
-    solver: str,
-    iters: int,
-    pl,
-    mem: Membership,
+    local: _LocalStage | None,
+    agg: tuple | None,
     ndim: int,
-    from_covs: bool = False,
     with_ref: bool = False,
-    form: str = "cov",
-    standardize: str | None = None,
 ):
-    """The jitted mesh program of ``distributed_pca`` (rows) or
-    ``distributed_pca_from_covs`` (``from_covs``; ``with_ref`` when the
-    caller passes a reference), built once per set of static inputs.
-    ``form`` and ``standardize`` are the rows' local solve
-    (``_local_pca_basis``).
+    """The jitted ``shard_map`` of a local stage and an aggregation over
+    the mesh, built once per set of static inputs: the one place a mesh
+    program of this package is built.
+
+    ``local`` maps each shard's ndim-dimensional block to its basis
+    (``None``: the blocks are (1, d, r) bases already).  ``agg`` is
+    (n_iter, plan, membership) for ``procrustes_average_collective``, or
+    ``None`` to return the local bases; ``with_ref`` takes the
+    aggregation's reference as a second, replicated argument.  The result
+    is (shards, d, r), sharded along its leading axis.
 
     The arguments are everything the traced function closes over, so a
     repeated call reuses one ``jax.jit`` object and JAX dispatches it
@@ -453,22 +497,15 @@ def _pca_program(
     axes, _, _ = _agg_axes(mesh, data_axis, hier)
 
     def shard_fn(x_shard: jax.Array, *ref: jax.Array) -> jax.Array:
-        if from_covs:
-            # x_shard: (m_local, d, d); m_local == 1 when m == mesh size.
-            v, _ = local_eigenbasis(
-                jnp.mean(x_shard, axis=0), r, method=solver, iters=iters
+        v = x_shard[0] if local is None else local(x_shard)
+        if agg is not None:
+            n_iter, pl, mem = agg
+            v = procrustes_average_collective(
+                v, axis_name=data_axis, n_iter=n_iter,
+                ref=ref[0] if with_ref else None, plan=pl, membership=mem,
+                pod_axis=POD_AXIS if hier else None,
             )
-        else:
-            v = _local_pca_basis(
-                x_shard, r, solver=solver, iters=iters, backend=pl.backend,
-                form=form, standardize=standardize,
-            )
-        out = procrustes_average_collective(
-            v, axis_name=data_axis, n_iter=n_iter,
-            ref=ref[0] if with_ref else None, plan=pl, membership=mem,
-            pod_axis=POD_AXIS if hier else None,
-        )
-        return out[None]  # keep a sharded leading axis; identical on every shard
+        return v[None]  # keep a sharded leading axis
 
     spec_in = P(axes, *(None,) * (ndim - 1))
     return jax.jit(
@@ -540,7 +577,7 @@ def distributed_pca(
     The jitted mesh program is built once per set of static arguments
     (mesh, ``data_axis``, resolved plan and membership, ``r``, ``n_iter``,
     ``solver``, ``iters``, ``samples.ndim``, the local form and
-    ``standardize``; ``_pca_program``), so a repeated call dispatches it
+    ``standardize``; ``_mesh_program``), so a repeated call dispatches it
     without tracing or lowering again.
 
     The call's host time is the profiler span ``repro.pca``; plan
@@ -551,27 +588,21 @@ def distributed_pca(
     executable inside the span; 0 when it reused one; and ``form``, the
     local solve's form (``cov`` or ``rows``).
     """
-    from repro.plan.planner import resolve_plan
-
     job = next(_JOB_IDS)
     with jax.profiler.TraceAnnotation("repro.pca", job=job):
-        hier = _hier_requested(topology, plan)
-        _, m, pods = _agg_axes(mesh, data_axis, hier)
         form = _shard_form(samples, mesh)
-        mem = resolve_membership(membership, m)
-        with jax.profiler.TraceAnnotation("repro.pca.plan", job=job):
-            pl = resolve_plan(
-                plan, m=mem.m, d=samples.shape[-1], r=r,
-                n_iter=n_iter, backend=backend, topology=topology,
-                polar=polar, orth=orth, comm_bits=comm_bits, membership=mem,
-                pods=pods,
-            )
-        misses = _pca_program.cache_info().misses
-        fn = _pca_program(
-            mesh, data_axis, hier, r, n_iter, solver, iters, pl, mem,
-            samples.ndim, form=form, standardize=standardize,
+        hier, _, mem, pl = _resolve_job(
+            mesh, data_axis, samples.shape[-1], r, plan=plan,
+            topology=topology, membership=membership, job=job,
+            n_iter=n_iter, backend=backend, polar=polar, orth=orth,
+            comm_bits=comm_bits,
         )
-        built = int(_pca_program.cache_info().misses > misses)
+        local = _LocalStage("rows", r, solver, iters, backend=pl.backend,
+                            form=form, standardize=standardize)
+        misses = _mesh_program.cache_info().misses
+        fn = _mesh_program(mesh, data_axis, hier, local, (n_iter, pl, mem),
+                           samples.ndim)
+        built = int(_mesh_program.cache_info().misses > misses)
         with jax.profiler.TraceAnnotation(
             "repro.pca.dispatch", job=job, built=built, form=form
         ):
@@ -613,21 +644,13 @@ def distributed_pca_from_covs(
     program serves every refresh, and the plan is priced with
     ``ref_broadcast=False`` (no reference broadcast round on the wire).
     """
-    from repro.plan.planner import resolve_plan
-
-    hier = _hier_requested(topology, plan)
-    _, m, pods = _agg_axes(mesh, data_axis, hier)
-    mem = resolve_membership(membership, m)
-    pl = resolve_plan(
-        plan, m=mem.m, d=covs.shape[-1], r=r,
-        n_iter=n_iter, backend=backend, topology=topology,
-        polar=polar, orth=orth, comm_bits=comm_bits, membership=mem,
-        pods=pods, ref_broadcast=(ref is None),
+    hier, _, mem, pl = _resolve_job(
+        mesh, data_axis, covs.shape[-1], r, plan=plan, topology=topology,
+        membership=membership, n_iter=n_iter, backend=backend, polar=polar,
+        orth=orth, comm_bits=comm_bits, ref_broadcast=(ref is None),
     )
-
     with_ref = ref is not None
-    fn = _pca_program(
-        mesh, data_axis, hier, r, n_iter, solver, iters, pl, mem, covs.ndim,
-        from_covs=True, with_ref=with_ref,
-    )
+    local = _LocalStage("covs", r, solver, iters)
+    fn = _mesh_program(mesh, data_axis, hier, local, (n_iter, pl, mem),
+                       covs.ndim, with_ref)
     return (fn(covs, ref) if with_ref else fn(covs))[0]

@@ -83,6 +83,7 @@ __all__ = [
     "score_cells",
     "plan_aggregation",
     "resolve_plan",
+    "plan_pins",
     "explain",
     "format_plan_table",
 ]
@@ -758,6 +759,26 @@ def resolve_plan(
     raise ValueError(
         f"plan must be None, 'auto', or a Plan, got {plan!r}"
     )
+
+
+def plan_pins(
+    plan: Union[None, str, Plan],
+    *,
+    backend: Optional[str] = None,
+    topology: Optional[str] = None,
+    polar: Optional[str] = None,
+    orth: Optional[str] = None,
+    ring_chunk: Optional[int] = None,
+    comm_bits=None,
+) -> dict:
+    """The knob pins a later re-plan keeps (``runtime.elastic.replan``):
+    a resolved ``Plan``'s own knobs, else the knob arguments as given."""
+    if isinstance(plan, Plan):
+        backend, topology, polar, orth = (
+            plan.backend, plan.topology, plan.polar, plan.orth)
+        ring_chunk, comm_bits = plan.ring_chunk, plan.comm_bits
+    return dict(backend=backend, topology=topology, polar=polar, orth=orth,
+                ring_chunk=ring_chunk, comm_bits=comm_bits)
 
 
 # ---------------------------------------------------------------------------

@@ -39,10 +39,9 @@ import dataclasses
 from typing import Any, List, Optional
 
 import jax
-from jax.sharding import PartitionSpec as P
 
 from repro.comm.membership import Membership
-from repro.plan.planner import Plan, plan_aggregation
+from repro.plan.planner import Plan, plan_aggregation, plan_pins
 from repro.runtime.straggler import StepTimer
 
 __all__ = [
@@ -163,7 +162,10 @@ def elastic_pca(
     The local bases are computed once (each shard keeps its data and its
     local top-r solution for the whole run); the refinement rounds are
     then scheduled in *groups* of consecutive rounds sharing one
-    membership, each group one jitted shard_map collective:
+    membership, each group one jitted shard_map collective.  The local
+    stage and every group come from ``core.distributed``'s program
+    builder, so a later call with the same shapes, knobs and schedule
+    reuses their programs without tracing or lowering again:
 
       * ``injector`` (``runtime.fault.FailureInjector``) supplies the
         (shard, round) kill/recover schedule via ``membership_at``;
@@ -189,24 +191,18 @@ def elastic_pca(
     with each group's call: telescoping within a group, a clean zero
     residual whenever membership changes.
     """
-    from repro.core.distributed import _local_pca_basis, _shard_form
-    from repro.plan.planner import resolve_plan
+    from repro.core.distributed import (
+        _LocalStage, _mesh_program, _resolve_job, _shard_form,
+    )
 
     m = mesh.shape[data_axis]
     d = samples.shape[-1]
     n_iter = max(n_iter, 1)
     timer = timer or StepTimer()
-    if isinstance(plan, Plan):
-        pins = dict(
-            backend=plan.backend, topology=plan.topology, polar=plan.polar,
-            orth=plan.orth, ring_chunk=plan.ring_chunk,
-            comm_bits=plan.comm_bits,
-        )
-    else:
-        pins = dict(
-            backend=backend, topology=topology, polar=polar, orth=orth,
-            ring_chunk=ring_chunk, comm_bits=comm_bits,
-        )
+    pins = plan_pins(
+        plan, backend=backend, topology=topology, polar=polar, orth=orth,
+        ring_chunk=ring_chunk, comm_bits=comm_bits,
+    )
 
     def membership_at(t: int) -> Membership:
         if injector is None:
@@ -224,61 +220,19 @@ def elastic_pca(
 
         monitor.on_escalate = _escalate
 
-    mem0 = membership_at(0)
-    pl = resolve_plan(
-        plan, m=m, d=d, r=r, n_iter=n_iter, ref_broadcast=True,
-        device_kind=device_kind, calibration=calibration,
-        membership=mem0, **pins,
+    hier, _, _, pl = _resolve_job(
+        mesh, data_axis, d, r, plan=plan, membership=membership_at(0),
+        n_iter=n_iter, ref_broadcast=True, device_kind=device_kind,
+        calibration=calibration, **pins,
     )
 
     # Local stage, once: each shard's top-r basis, in the form its shape
     # takes, stacked sharded along the axis.  The planned backend routes
     # it, like the driver in ``core.distributed``.
-    form = _shard_form(samples, mesh)
-    local_fn = jax.jit(
-        jax.shard_map(
-            lambda x: _local_pca_basis(
-                x, r, solver=solver, iters=iters, backend=pl.backend,
-                form=form,
-            )[None],
-            mesh=mesh,
-            in_specs=P(data_axis, *(None,) * (samples.ndim - 1)),
-            out_specs=P(data_axis, None, None),
-            check_vma=False,
-        )
-    )
-    v_stack = local_fn(samples)  # (m, d, r)
-
-    def run_group(ref, mem: Membership, g: int, group_plan: Plan):
-        from repro.core.distributed import procrustes_average_collective
-
-        if ref is None:
-            def fn(v_blk):
-                out = procrustes_average_collective(
-                    v_blk[0], axis_name=data_axis, n_iter=g,
-                    plan=group_plan, membership=mem,
-                )
-                return out[None]
-
-            wrapped = jax.shard_map(
-                fn, mesh=mesh, in_specs=P(data_axis, None, None),
-                out_specs=P(data_axis, None, None), check_vma=False,
-            )
-            return jax.jit(wrapped)(v_stack)
-
-        def fn(v_blk, ref_arr):
-            out = procrustes_average_collective(
-                v_blk[0], axis_name=data_axis, n_iter=g, ref=ref_arr,
-                plan=group_plan, membership=mem,
-            )
-            return out[None]
-
-        wrapped = jax.shard_map(
-            fn, mesh=mesh,
-            in_specs=(P(data_axis, None, None), P(None, None)),
-            out_specs=P(data_axis, None, None), check_vma=False,
-        )
-        return jax.jit(wrapped)(v_stack, ref)
+    local = _LocalStage("rows", r, solver, iters, backend=pl.backend,
+                        form=_shard_form(samples, mesh))
+    v_stack = _mesh_program(mesh, data_axis, hier, local, None,
+                            samples.ndim)(samples)  # (m, d, r)
 
     events: List[RoundEvent] = []
     replans = 0
@@ -314,7 +268,9 @@ def elastic_pca(
                 round_index=t, rounds=g, reason=reason,
                 membership=mem, plan=pl,
             ))
-        stacked = run_group(ref, mem, g, pl)
+        fn = _mesh_program(mesh, data_axis, hier, None, (g, pl, mem), 3,
+                           ref is not None)
+        stacked = fn(v_stack) if ref is None else fn(v_stack, ref)
         # Every topology leaves the answer mesh-replicated (the masked
         # ring syncs it explicitly), so any row works; the first
         # survivor's is the canonical one.

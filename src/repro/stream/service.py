@@ -18,9 +18,10 @@
     refreshes on stationary data agree element-wise (no sign or rotation
     flips) — the same machinery ``optim.eigen_compress`` trusts across
     basis refreshes, now load-bearing for a service whose clients hold
-    projections from the previous basis.  Each (membership, has-ref) pair
-    compiles its mesh program once and is reused every refresh — the
-    reference enters as a replicated *argument*, never a closure capture;
+    projections from the previous basis.  The mesh program comes from
+    ``core.distributed``'s shared builder, cached on (plan, membership,
+    has-ref), and is reused every refresh — the reference enters as a
+    replicated *argument*, never a closure capture;
   * **queries** — ``project(queries)`` is a plain replicated matmul
     against the served basis, double-buffered: a refresh writes the new
     basis into the back buffer and flips the front index only when the
@@ -64,17 +65,11 @@ from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
-from repro.comm import DATA_AXIS, POD_AXIS
+from repro.comm import DATA_AXIS
 from repro.comm.membership import Membership, resolve_membership
-from repro.core.distributed import (
-    _agg_axes,
-    _hier_requested,
-    procrustes_average_collective,
-)
-from repro.core.subspace import local_eigenbasis
-from repro.plan.planner import Plan, resolve_plan
+from repro.core.distributed import _LocalStage, _mesh_program, _resolve_job
+from repro.plan.planner import Plan, plan_pins
 from repro.runtime.elastic import RoundEvent, replan, transition_reason
 from repro.stream.accumulator import update as _acc_update
 
@@ -191,28 +186,18 @@ class SubspaceService:
         self.cadence = cadence
         self.drift_threshold = drift_threshold
         self.solver, self.iters = solver, iters
-        self._hier = _hier_requested(topology, plan)
-        self._axes, self.m, self._pods = _agg_axes(mesh, data_axis, self._hier)
-        self._mem = resolve_membership(membership, self.m)
-        if isinstance(plan, Plan):
-            self._pins = dict(
-                backend=plan.backend, topology=plan.topology,
-                polar=plan.polar, orth=plan.orth,
-                ring_chunk=plan.ring_chunk, comm_bits=plan.comm_bits,
-            )
-        else:
-            self._pins = dict(
-                backend=backend, topology=topology, polar=polar, orth=orth,
-                ring_chunk=ring_chunk, comm_bits=comm_bits,
-            )
+        self._pins = plan_pins(
+            plan, backend=backend, topology=topology, polar=polar, orth=orth,
+            ring_chunk=ring_chunk, comm_bits=comm_bits,
+        )
         self._device_kind = device_kind
         self._calibration = calibration
-        self._plan = resolve_plan(
-            plan, m=self._mem.m, d=d, r=r, n_iter=self.n_iter,
-            ref_broadcast=False, device_kind=device_kind,
-            calibration=calibration, membership=self._mem,
-            pods=self._pods, **self._pins,
+        self._hier, self._pods, self._mem, self._plan = _resolve_job(
+            mesh, data_axis, d, r, plan=plan, membership=membership,
+            n_iter=self.n_iter, ref_broadcast=False, device_kind=device_kind,
+            calibration=calibration, **self._pins,
         )
+        self.m = self._mem.m
         dt = jnp.dtype(dtype)
         self._state = {
             "count": jnp.zeros((self.m,), dt),
@@ -230,7 +215,6 @@ class SubspaceService:
         self._events: List[RoundEvent] = []
         self._last_drift: Optional[float] = None
         self._last_jump: Optional[float] = None
-        self._refresh_cache: Dict[Any, Any] = {}
         # ``project`` runs on callers' threads: its count takes the lock.
         self._query_lock = threading.Lock()
         self._queries = 0
@@ -284,43 +268,15 @@ class SubspaceService:
         ``with_ref=True`` is the steady-state program
         ``fn(covs, ref) -> (m, d, r)``; ``with_ref=False`` the bootstrap
         program ``fn(covs)`` that broadcasts the first survivor's basis.
-        Cached per (membership, with_ref): every steady-state refresh
-        reuses one compiled program, the reference riding in as a
-        replicated argument.
+        Both come from ``core.distributed``'s program builder, cached on
+        (plan, membership, with_ref): every steady-state refresh reuses one
+        compiled program, the reference riding in as a replicated argument.
         """
-        key = (self._mem, bool(with_ref))
-        fn = self._refresh_cache.get(key)
-        if fn is not None:
-            return fn
-        plan_, mem = self._plan, self._mem
-        axes = self._axes
-        pod_axis = POD_AXIS if self._hier else None
-        r, n_iter = self.r, self.n_iter
-        solver, iters, data_axis = self.solver, self.iters, self.data_axis
-
-        def shard_fn(cov_shard, ref_arg):
-            cov = jnp.mean(cov_shard, axis=0)
-            v, _ = local_eigenbasis(cov, r, method=solver, iters=iters)
-            out = procrustes_average_collective(
-                v, axis_name=data_axis, n_iter=n_iter, ref=ref_arg,
-                plan=plan_, membership=mem, pod_axis=pod_axis,
-            )
-            return out[None]
-
-        if with_ref:
-            fn = jax.jit(jax.shard_map(
-                shard_fn, mesh=self.mesh,
-                in_specs=(P(axes, None, None), P(None, None)),
-                out_specs=P(axes, None, None), check_vma=False,
-            ))
-        else:
-            fn = jax.jit(jax.shard_map(
-                lambda c: shard_fn(c, None), mesh=self.mesh,
-                in_specs=P(axes, None, None),
-                out_specs=P(axes, None, None), check_vma=False,
-            ))
-        self._refresh_cache[key] = fn
-        return fn
+        local = _LocalStage("covs", self.r, self.solver, self.iters)
+        return _mesh_program(
+            self.mesh, self.data_axis, self._hier, local,
+            (self.n_iter, self._plan, self._mem), 3, with_ref,
+        )
 
     def _sync(self, read):
         """Run ``read``, a blocking device read, and count its wait."""

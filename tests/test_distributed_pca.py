@@ -110,25 +110,29 @@ D, R = 32, 3
 
 
 def _cache():
-    from repro.core.distributed import _pca_program
+    from repro.core.distributed import _mesh_program
 
-    info = _pca_program.cache_info()
+    info = _mesh_program.cache_info()
     return info.hits, info.misses
 
 
 def _fresh_program(x, mesh, r, *, n_iter=1, solver="eigh", iters=30,
-                   plan=None, membership=None, **kw):
-    """``distributed_pca``'s program for these arguments, built with the
-    cache bypassed."""
+                   plan=None, membership=None, from_covs=False,
+                   with_ref=False):
+    """``distributed_pca``'s (or, ``from_covs``,
+    ``distributed_pca_from_covs``') program for these arguments, built
+    with the cache bypassed."""
     from repro.comm.membership import resolve_membership
-    from repro.core.distributed import _pca_program
+    from repro.core.distributed import _LocalStage, _mesh_program
     from repro.plan.planner import resolve_plan
 
     mem = resolve_membership(membership, mesh.shape["data"])
     pl = resolve_plan(plan, m=mem.m, d=x.shape[-1], r=r, n_iter=n_iter,
                       membership=mem)
-    return _pca_program.__wrapped__(
-        mesh, "data", False, r, n_iter, solver, iters, pl, mem, x.ndim, **kw
+    local = (_LocalStage("covs", r, solver, iters) if from_covs else
+             _LocalStage("rows", r, solver, iters, backend=pl.backend))
+    return _mesh_program.__wrapped__(
+        mesh, "data", False, local, (n_iter, pl, mem), x.ndim, with_ref
     )
 
 
@@ -184,7 +188,8 @@ def program_cases():
         import jax, numpy as np
         from jax.sharding import Mesh
         from repro.comm.membership import Membership, resolve_membership
-        from repro.core.distributed import _pca_program, distributed_pca
+        from repro.core.distributed import (
+            _LocalStage, _mesh_program, distributed_pca)
         from repro.data import synthetic as syn
         from repro.launch.mesh import make_mesh
         from repro.plan.planner import resolve_plan
@@ -207,15 +212,16 @@ def program_cases():
         }
 
         def cache():
-            info = _pca_program.cache_info()
+            info = _mesh_program.cache_info()
             return info.hits, info.misses
 
         def fresh(mesh, r, n_iter, solver, plan, membership):
             mem = resolve_membership(membership, 4)
             pl = resolve_plan(plan, m=4, d=D, r=r, n_iter=n_iter,
                               membership=mem)
-            return _pca_program.__wrapped__(
-                mesh, "data", False, r, n_iter, solver, 30, pl, mem, 2)(x)[0]
+            local = _LocalStage("rows", r, solver, 30, backend=pl.backend)
+            return _mesh_program.__wrapped__(
+                mesh, "data", False, local, (n_iter, pl, mem), 2)(x)[0]
 
         def call(kw):
             kw = dict(kw)

@@ -516,6 +516,49 @@ def test_elastic_recovery_rejoins_via_alignment():
 
 
 @pytest.mark.slow
+def test_elastic_pca_second_call_builds_no_program():
+    """The local stage and every group come from the shared program
+    builder: a second run with the same shapes and fault schedule builds
+    no program and returns the same basis, bit for bit."""
+    out = run_with_devices(
+        """
+        import jax, numpy as np
+        from repro.core.distributed import _mesh_program
+        from repro.launch.mesh import make_mesh
+        from repro.runtime.elastic import elastic_pca
+        from repro.runtime.fault import FailureInjector
+
+        m, n, d, r = 4, 64, 24, 3
+        samples = jax.random.normal(jax.random.PRNGKey(0), (m * n, d))
+        mesh = make_mesh((m,), ("data",))
+
+        def run():
+            inj = FailureInjector(fail_at=((2, 1),), recover_at=((2, 3),))
+            return elastic_pca(samples, mesh, r, n_iter=4, solver="eigh",
+                               injector=inj)
+
+        first = run()
+        before = _mesh_program.cache_info()
+        second = run()
+        after = _mesh_program.cache_info()
+        print("REASONS", ",".join(e.reason for e in second.events))
+        print("MISSES", after.misses - before.misses)
+        print("HITS", after.hits - before.hits)
+        print("SAME", np.array_equal(np.asarray(first.basis),
+                                     np.asarray(second.basis)))
+        """,
+        n_devices=4,
+    )
+    lines = dict(ln.split(None, 1) for ln in out.strip().splitlines()
+                 if ln.strip())
+    assert lines["REASONS"] == "initial,failure,recovery"
+    assert lines["MISSES"] == "0"
+    # The local stage and one program per group of rounds.
+    assert lines["HITS"] == "4"
+    assert lines["SAME"] == "True"
+
+
+@pytest.mark.slow
 def test_masked_ring_hlo_bytes_match_cost_model():
     """The degraded ring's compiled program bills exactly what
     ``comm_cost(..., membership=)`` predicts: m'-1 survivor hops per

@@ -19,8 +19,9 @@ import pytest
 from conftest import run_with_devices, subspace_dist64
 
 from repro.core.distributed import (
+    _LocalStage,
     _local_form,
-    _pca_program,
+    _mesh_program,
     distributed_pca,
     procrustes_average_collective,
 )
@@ -254,8 +255,9 @@ def test_the_covariance_form_program_is_unchanged(solver):
     mem = resolve_membership(None, 1)
     pl = resolve_plan(None, m=1, d=d, r=r, n_iter=N_ITER, membership=mem)
     assert _local_form(d, None) == "cov"
-    new = _pca_program.__wrapped__(mesh, "data", False, r, N_ITER, solver,
-                                   ITERS, pl, mem, 2)
+    local = _LocalStage("rows", r, solver, ITERS, backend=pl.backend)
+    new = _mesh_program.__wrapped__(mesh, "data", False, local,
+                                    (N_ITER, pl, mem), 2)
 
     def shard_fn(x_shard):
         v, _ = local_eigenbasis(empirical_covariance(x_shard,

@@ -240,6 +240,37 @@ def test_service_stats_and_guards():
     assert out.shape == (10, 2)
 
 
+@pytest.mark.parametrize("with_ref", [False, True],
+                         ids=["bootstrap", "steady"])
+def test_refresh_reuses_the_from_covs_program(with_ref):
+    """A refresh runs ``distributed_pca_from_covs``'s program: after a
+    ``from_covs`` call with the service's plan and membership, the
+    refresh builds no program and serves the same basis, bit for bit."""
+    from repro.core.distributed import (
+        _mesh_program,
+        distributed_pca_from_covs,
+    )
+    from repro.stream.service import _safe_covs
+
+    d, r, nper = 40, 3, 256
+    mesh = make_aggregation_mesh()
+    _, rows = _spiked_stream(3, d, r, 2 * nper)
+    svc = SubspaceService(mesh, d, r, cadence=1)
+    if with_ref:
+        svc.observe(rows[:nper][None])  # the bootstrap refresh
+    ref = svc.basis
+    kw = dict(n_iter=svc.n_iter, solver=svc.solver, iters=svc.iters,
+              plan=svc.plan, membership=svc.membership, ref=ref)
+    distributed_pca_from_covs(
+        empirical_covariance(rows[nper:])[None], mesh, r, **kw)
+    info = _mesh_program.cache_info()
+    svc.observe(rows[nper:][None])
+    after = _mesh_program.cache_info()
+    assert (after.hits, after.misses) == (info.hits + 1, info.misses)
+    want = distributed_pca_from_covs(_safe_covs(svc._state), mesh, r, **kw)
+    np.testing.assert_array_equal(np.asarray(svc.basis), np.asarray(want))
+
+
 def test_query_path_has_zero_collectives():
     """The serving claim: the steady-state query program is a replicated
     matmul — no collective primitive anywhere in its jaxpr."""

@@ -23,7 +23,7 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core.distributed import _pca_program, distributed_pca
+from repro.core.distributed import _mesh_program, distributed_pca
 from repro.data import synthetic as syn
 from repro.launch.mesh import make_aggregation_mesh
 from repro.stream.service import SubspaceService
@@ -88,7 +88,7 @@ def run(tmp_path_factory):
     queries = _rows(QUERIES * 8, seed=2).reshape(QUERIES, 8, D)
     plain = _workload(mesh, rows, chunks, queries)
     # The traced run's first PCA call then builds its program again.
-    _pca_program.cache_clear()
+    _mesh_program.cache_clear()
     log_dir = str(tmp_path_factory.mktemp("trace"))
     jax.profiler.start_trace(log_dir)
     try:
