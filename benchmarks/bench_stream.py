@@ -109,7 +109,8 @@ def run_sweep(
                 svc.observe(rows)  # one chunk per shard seeds the state
                 covs = _safe_covs(svc._state)
                 ref = svc.basis  # the observe() bootstrapped a basis
-                fn = svc.refresh_fn(with_ref=True)
+                # The default solver (eigh) refreshes in one program.
+                (fn,) = svc.refresh_programs(with_ref=True)
                 rec = {
                     "workload": "stream-refresh",
                     "topology": "collective", "comm": comm,

@@ -76,7 +76,13 @@ from repro.core.eigenspace import refinement_rounds
 from repro.core.orthonorm import orthonormalize, resolve_orth
 from repro.core.precision import highest_precision
 from repro.core.rowop import check_standardize, row_operator
-from repro.core.subspace import local_eigenbasis, subspace_iteration
+from repro.core.subspace import (
+    local_eigenbasis,
+    subspace_finish,
+    subspace_iteration,
+    subspace_start,
+    subspace_steps,
+)
 
 __all__ = [
     "broadcast_from",
@@ -445,6 +451,13 @@ class _LocalStage:
     ``kind="rows"``: raw rows through ``_local_pca_basis`` (``backend``,
     ``form`` and ``standardize`` as there); ``kind="covs"``: stacked
     (m_local, d, d) local matrices, averaged, then solved.
+
+    The slices of a ``covs`` subspace solve cut into programs of their own
+    (``SubspaceService.refresh``), each ``iters`` steps long:
+    ``"covs_head"`` starts the solve as ``subspace_iteration`` does,
+    ``"covs_steps"`` continues it from the running basis, and
+    ``"covs_tail"`` continues it and finishes (Rayleigh--Ritz).  The head
+    and the middle slices return the running, unfinished basis.
     """
 
     kind: str
@@ -455,17 +468,32 @@ class _LocalStage:
     form: str = "cov"
     standardize: str | None = None
 
-    def __call__(self, x_shard: jax.Array) -> jax.Array:
+    @property
+    def takes_basis(self) -> bool:
+        """Whether the stage continues a solve: it then takes each
+        shard's running (d, r) basis as a second, sharded argument."""
+        return self.kind in ("covs_steps", "covs_tail")
+
+    def __call__(self, x_shard: jax.Array,
+                 q: jax.Array | None = None) -> jax.Array:
+        if self.kind == "rows":
+            return _local_pca_basis(
+                x_shard, self.r, solver=self.solver, iters=self.iters,
+                backend=self.backend, form=self.form,
+                standardize=self.standardize,
+            )
+        # x_shard: (m_local, d, d); m_local == 1 when m == mesh size.
+        x = jnp.mean(x_shard, axis=0)
         if self.kind == "covs":
-            # x_shard: (m_local, d, d); m_local == 1 when m == mesh size.
-            v, _ = local_eigenbasis(jnp.mean(x_shard, axis=0), self.r,
-                                    method=self.solver, iters=self.iters)
+            v, _ = local_eigenbasis(x, self.r, method=self.solver,
+                                    iters=self.iters)
             return v
-        return _local_pca_basis(
-            x_shard, self.r, solver=self.solver, iters=self.iters,
-            backend=self.backend, form=self.form,
-            standardize=self.standardize,
-        )
+        if self.kind == "covs_head":
+            q = subspace_start(x, self.r)
+        q = subspace_steps(x, q, self.iters)
+        if self.kind == "covs_tail":
+            q, _ = subspace_finish(x, q)
+        return q
 
 
 @functools.lru_cache(maxsize=32)
@@ -483,10 +511,12 @@ def _mesh_program(
     program of this package is built.
 
     ``local`` maps each shard's ndim-dimensional block to its basis
-    (``None``: the blocks are (1, d, r) bases already).  ``agg`` is
+    (``None``: the blocks are (1, d, r) bases already); a stage that
+    ``takes_basis`` also takes the running bases, (shards, d, r) sharded
+    along the leading axis, as the second argument.  ``agg`` is
     (n_iter, plan, membership) for ``procrustes_average_collective``, or
     ``None`` to return the local bases; ``with_ref`` takes the
-    aggregation's reference as a second, replicated argument.  The result
+    aggregation's reference as a last, replicated argument.  The result
     is (shards, d, r), sharded along its leading axis.
 
     The arguments are everything the traced function closes over, so a
@@ -495,9 +525,16 @@ def _mesh_program(
     out of the key: ``jax.jit`` keys its executables on them itself.
     """
     axes, _, _ = _agg_axes(mesh, data_axis, hier)
+    takes_basis = local is not None and local.takes_basis
 
     def shard_fn(x_shard: jax.Array, *ref: jax.Array) -> jax.Array:
-        v = x_shard[0] if local is None else local(x_shard)
+        if local is None:
+            v = x_shard[0]
+        elif takes_basis:
+            q, *ref = ref
+            v = local(x_shard, q[0])
+        else:
+            v = local(x_shard)
         if agg is not None:
             n_iter, pl, mem = agg
             v = procrustes_average_collective(
@@ -508,10 +545,14 @@ def _mesh_program(
         return v[None]  # keep a sharded leading axis
 
     spec_in = P(axes, *(None,) * (ndim - 1))
+    in_specs = (
+        (spec_in,)
+        + ((P(axes, None, None),) if takes_basis else ())
+        + ((P(None, None),) if with_ref else ())
+    )
     return jax.jit(
         jax.shard_map(
-            shard_fn, mesh=mesh,
-            in_specs=(spec_in, P(None, None)) if with_ref else (spec_in,),
+            shard_fn, mesh=mesh, in_specs=in_specs,
             out_specs=P(axes, None, None), check_vma=False,
         )
     )

@@ -455,13 +455,15 @@ def dryrun_paper_pca(
         }
     if stream_steps:
         # Streaming-service lane: the steady-state refresh program (covs
-        # and previous basis in, next basis out) plus the query program.
+        # and previous basis in, next basis out; of a refresh cut into
+        # slices, the last slice, which holds the aggregation and so every
+        # collective) plus the query program.
         from repro.stream import SubspaceService
 
         svc = SubspaceService(
             mesh, pcfg.d, pcfg.r, n_iter=pcfg.n_iter, cadence=stream_steps,
             solver=pcfg.solver, iters=pcfg.solver_iters, plan=pl,
-            membership=mem,
+            membership=mem, device_kind=plan_device,
         )
         s_cost = comm_cost(
             topo, m=m_agg, d=pcfg.d, r=pcfg.r, n_iter=pcfg.n_iter,
@@ -478,8 +480,15 @@ def dryrun_paper_pca(
         record["predicted_refresh_bits_per_step"] = s_cost.bits / stream_steps
         covs_like = jax.ShapeDtypeStruct((m_agg, pcfg.d, pcfg.d), jnp.float32)
         ref_like = jax.ShapeDtypeStruct((pcfg.d, pcfg.r), jnp.float32)
+        programs = svc.refresh_programs(with_ref=True)
+        # The last slice of several also takes the running bases.
+        basis_like = (
+            (jax.ShapeDtypeStruct((m_agg, pcfg.d, pcfg.r), jnp.float32),)
+            if len(programs) > 1 else ()
+        )
+        record["refresh_slices"] = len(programs)
         t0 = time.time()
-        lowered = svc.refresh_fn(with_ref=True).lower(covs_like, ref_like)
+        lowered = programs[-1].lower(covs_like, *basis_like, ref_like)
         t_lower = time.time() - t0
         t0 = time.time()
         compiled = lowered.compile()

@@ -18,14 +18,18 @@
     refreshes on stationary data agree element-wise (no sign or rotation
     flips) — the same machinery ``optim.eigen_compress`` trusts across
     basis refreshes, now load-bearing for a service whose clients hold
-    projections from the previous basis.  The mesh program comes from
-    ``core.distributed``'s shared builder, cached on (plan, membership,
-    has-ref), and is reused every refresh — the reference enters as a
-    replicated *argument*, never a closure capture;
+    projections from the previous basis.  A subspace solve that models
+    longer than ``SLICE_BUDGET_S`` on the chip runs as slices, one short
+    program each, with one slice queued behind the running one: a query
+    enqueued during a refresh waits behind two slices at most, not
+    behind the whole solve (``slice_steps``).  The mesh programs come
+    from ``core.distributed``'s shared builder, cached on (plan,
+    membership, slice, has-ref), and are reused every refresh — the
+    reference enters as a replicated *argument*, never a closure capture;
   * **queries** — ``project(queries)`` is a plain replicated matmul
     against the served basis, double-buffered: a refresh writes the new
     basis into the back buffer and flips the front index only when the
-    collective has returned, so a query never observes a half-written
+    last slice has returned, so a query never observes a half-written
     refresh.  The steady-state query program contains zero collectives
     (``tests/test_stream.py`` pins this on the jaxpr).
 
@@ -49,12 +53,13 @@ accumulator is valid, merely stale, and re-enters by Procrustes-aligning
 to the served basis like any other shard.
 
 Staleness/drift/refresh metrics live in ``stats``, with the count of
-queries and of the blocking device reads the service made (refresh's two,
-drift's one) and the host seconds spent in those reads.  Each host step
-is a profiler span (``repro.service.observe``, ``.update``, ``.refresh``
-with its ``.wait_count``, ``.dispatch`` and ``.wait_jump``, ``.project``,
-``.drift``) carrying its ``step``, ``refresh`` or ``query`` id.  Design:
-DESIGN.md §10.
+refresh slices dispatched, of queries and of the blocking device reads
+the service made (refresh's two, drift's one) and the host seconds spent
+in those reads.  Each host step is a profiler span
+(``repro.service.observe``, ``.update``, ``.refresh`` with its
+``.wait_count``, ``.dispatch``, ``.slice``, ``.wait_slice`` and
+``.wait_jump``, ``.project``, ``.drift``) carrying its ``step``,
+``refresh`` (and ``slice``) or ``query`` id.  Design: DESIGN.md §10.
 """
 
 from __future__ import annotations
@@ -69,11 +74,46 @@ import jax.numpy as jnp
 from repro.comm import DATA_AXIS
 from repro.comm.membership import Membership, resolve_membership
 from repro.core.distributed import _LocalStage, _mesh_program, _resolve_job
-from repro.plan.planner import Plan, plan_pins
+from repro.plan.planner import Plan, _default_device_kind, plan_pins
+from repro.plan.roofline import device_model
 from repro.runtime.elastic import RoundEvent, replan, transition_reason
 from repro.stream.accumulator import update as _acc_update
 
-__all__ = ["SubspaceService", "basis_jump", "project"]
+__all__ = ["SubspaceService", "basis_jump", "project", "slice_steps"]
+
+# The modeled device time of one slice of a refresh's local solve: the
+# device time one query may wait behind one slice (behind two at most: the
+# refresh keeps one slice queued behind the running one).  2.5 ms modeled
+# is 4 iterations at d=8192, r=128 on a v5e, which runs them in ~3.5 ms:
+# under the ~5 ms a 4,096-row chunk's accumulate holds the device, so a
+# refresh stops setting the queries' tail, and long enough that the host
+# enqueues the next slice while the device still runs the queued one.
+SLICE_BUDGET_S = 2.5e-3
+
+
+def _iteration_s(d: int, r: int, device_kind: str) -> float:
+    """Modeled device time of one subspace iteration on a (d, d) matrix
+    (``repro.plan.roofline.device_model``): 2·d²·r FLOP at HIGHEST's six
+    bfloat16 passes, plus the (d, r) QR reading and writing its float32
+    operand."""
+    dev = device_model(device_kind)
+    return 6 * 2 * d * d * r / dev.peak_flops + 2 * 4 * d * r / dev.hbm_bw
+
+
+def slice_steps(d: int, r: int, iters: int, device_kind: str) -> tuple:
+    """Iterations per slice of an ``iters``-iteration subspace solve on a
+    (d, d) matrix, in order; they sum to ``iters``.
+
+    Each slice takes the most iterations whose modeled device time
+    (``_iteration_s``) fits ``SLICE_BUDGET_S``, at least one; the last
+    slice takes what is left.  A solve that fits the budget whole is one
+    slice.
+    """
+    k = max(int(SLICE_BUDGET_S // _iteration_s(d, r, device_kind)), 1)
+    if k >= iters:
+        return (iters,)
+    n, rest = divmod(iters, k)
+    return (k,) * n + ((rest,) if rest else ())
 
 
 def basis_jump(u: jax.Array, v: jax.Array) -> jax.Array:
@@ -198,6 +238,12 @@ class SubspaceService:
             calibration=calibration, **self._pins,
         )
         self.m = self._mem.m
+        # The refresh's local solve, cut into slices by the shapes and the
+        # chip (``slice_steps``); ``eigh`` has no iterations to cut.
+        self._slice_steps = (
+            slice_steps(d, r, iters, device_kind or _default_device_kind())
+            if solver == "subspace" else (iters,)
+        )
         dt = jnp.dtype(dtype)
         self._state = {
             "count": jnp.zeros((self.m,), dt),
@@ -211,6 +257,7 @@ class SubspaceService:
         self._step = 0
         self._last_refresh_step = 0
         self._refreshes = 0
+        self._refresh_slices = 0
         self._replans = 0
         self._events: List[RoundEvent] = []
         self._last_drift: Optional[float] = None
@@ -262,20 +309,39 @@ class SubspaceService:
 
     # -- refresh -----------------------------------------------------------
 
-    def refresh_fn(self, *, with_ref: bool = True):
-        """The jitted mesh program one refresh runs (for dryrun/tests).
+    def refresh_programs(self, *, with_ref: bool = True) -> tuple:
+        """The jitted mesh programs one refresh runs, in order (for
+        dryrun/tests): one per slice of its local solve.
 
-        ``with_ref=True`` is the steady-state program
-        ``fn(covs, ref) -> (m, d, r)``; ``with_ref=False`` the bootstrap
-        program ``fn(covs)`` that broadcasts the first survivor's basis.
-        Both come from ``core.distributed``'s program builder, cached on
-        (plan, membership, with_ref): every steady-state refresh reuses one
-        compiled program, the reference riding in as a replicated argument.
+        The first takes the stacked covariances ``covs``, each later one
+        ``covs`` and the previous one's output, the running per-shard
+        basis.  The last also runs the solve's finish and the aggregation:
+        with ``with_ref=True`` it takes the served basis as its last,
+        replicated argument (the steady state), with ``with_ref=False`` it
+        broadcasts the first survivor's basis (the bootstrap).  A refresh of one
+        slice is one program, ``fn(covs[, ref]) -> (m, d, r)``.  All come
+        from ``core.distributed``'s program builder, cached on their
+        static inputs: every refresh reuses the same compiled programs,
+        and slices of equal length share one.
         """
-        local = _LocalStage("covs", self.r, self.solver, self.iters)
-        return _mesh_program(
-            self.mesh, self.data_axis, self._hier, local,
-            (self.n_iter, self._plan, self._mem), 3, with_ref,
+        steps, r, solver = self._slice_steps, self.r, self.solver
+        if len(steps) == 1:
+            stages = [_LocalStage("covs", r, solver, self.iters)]
+        else:
+            stages = (
+                [_LocalStage("covs_head", r, solver, steps[0])]
+                + [_LocalStage("covs_steps", r, solver, k)
+                   for k in steps[1:-1]]
+                + [_LocalStage("covs_tail", r, solver, steps[-1])]
+            )
+        last = len(stages) - 1
+        return tuple(
+            _mesh_program(
+                self.mesh, self.data_axis, self._hier, stage,
+                (self.n_iter, self._plan, self._mem) if j == last else None,
+                3, with_ref and j == last,
+            )
+            for j, stage in enumerate(stages)
         )
 
     def _sync(self, read):
@@ -299,14 +365,29 @@ class SubspaceService:
             if rows == 0:
                 raise ValueError("refresh before any data: observe() first")
             prev = self._buffers[self._front]
+            programs = self.refresh_programs(with_ref=prev is not None)
+            last = len(programs) - 1
             with jax.profiler.TraceAnnotation(
                     "repro.service.refresh.dispatch", refresh=n):
                 covs = _safe_covs(self._state)
-                if prev is None:
-                    stacked = self.refresh_fn(with_ref=False)(covs)
-                else:
-                    stacked = self.refresh_fn(with_ref=True)(covs, prev)
-                new = stacked[self._mem.first_active]
+                outs = []
+                for j, fn in enumerate(programs):
+                    if j >= 2:
+                        # One slice queued behind the running one, no more:
+                        # a query enqueued now waits behind two at most.
+                        with jax.profiler.TraceAnnotation(
+                                "repro.service.refresh.wait_slice",
+                                refresh=n, slice=j):
+                            jax.block_until_ready(outs[j - 2])
+                    args = (covs, *outs[-1:])
+                    if j == last and prev is not None:
+                        args += (prev,)
+                    with jax.profiler.TraceAnnotation(
+                            "repro.service.refresh.slice", refresh=n,
+                            slice=j):
+                        outs.append(fn(*args))
+                    self._refresh_slices += 1
+                new = outs[-1][self._mem.first_active]
             if prev is not None:
                 with jax.profiler.TraceAnnotation(
                         "repro.service.refresh.wait_jump", refresh=n):
@@ -314,7 +395,7 @@ class SubspaceService:
                         lambda: float(basis_jump(prev, new)))
             back = 1 - self._front
             self._buffers[back] = new
-            self._front = back  # swap only after the collective returned
+            self._front = back  # swap only after the last slice returned
             self._refreshes += 1
             self._last_refresh_step = self._step
         return new
@@ -413,6 +494,7 @@ class SubspaceService:
             "step": self._step,
             "rows_seen": int(jnp.sum(self._state["count"])),
             "refreshes": self._refreshes,
+            "refresh_slices": self._refresh_slices,
             "queries": self._queries,
             "syncs": self._syncs,
             "sync_wait_s": self._sync_wait_s,

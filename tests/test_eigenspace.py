@@ -150,6 +150,29 @@ def test_subspace_iteration_agrees_with_eigh():
     np.testing.assert_allclose(np.asarray(lam_s), np.asarray(lam_e), rtol=1e-3)
 
 
+@pytest.mark.parametrize("k1", [0, 4, 13])
+def test_subspace_pieces_compose_to_subspace_iteration(k1):
+    """start, two runs of steps (k1 + k2 = iters) and finish, each its own
+    program as the slices of a sliced refresh are, give
+    ``subspace_iteration``'s basis and Ritz values bit for bit."""
+    from repro.core.subspace import (
+        subspace_finish,
+        subspace_start,
+        subspace_steps,
+    )
+
+    iters, r = 13, 4
+    tau = syn.spectrum_m1(64, r, delta=0.2)
+    sigma, _, _ = syn.covariance_from_spectrum(jax.random.PRNGKey(5), tau)
+    start = jax.jit(subspace_start, static_argnums=1)
+    steps = jax.jit(subspace_steps, static_argnums=2)
+    q = steps(sigma, steps(sigma, start(sigma, r), k1), iters - k1)
+    v, lam = jax.jit(subspace_finish)(sigma, q)
+    want_v, want_lam = subspace_iteration(sigma, r, iters=iters)
+    np.testing.assert_array_equal(np.asarray(v), np.asarray(want_v))
+    np.testing.assert_array_equal(np.asarray(lam), np.asarray(want_lam))
+
+
 def test_intdim_and_eigengap():
     tau = syn.spectrum_m2(128, 4, 24.0, delta=0.25)
     sigma, _, _ = syn.covariance_from_spectrum(jax.random.PRNGKey(7), tau)

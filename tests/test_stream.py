@@ -271,6 +271,71 @@ def test_refresh_reuses_the_from_covs_program(with_ref):
     np.testing.assert_array_equal(np.asarray(svc.basis), np.asarray(want))
 
 
+@pytest.mark.parametrize("m", [1, 4])
+def test_sliced_refresh_serves_the_unsliced_basis(m):
+    """A refresh cut into slices (a slice budget patched small enough that
+    the 10-iteration solve takes four: 3, 3, 3, 1) serves, refresh after
+    refresh, bootstrap and steady state alike, the basis the one-program
+    refresh serves, bit for bit; ``stats["refresh_slices"]`` counts every
+    slice dispatched."""
+    out = run_with_devices(
+        f"""
+        import jax, numpy as np
+        from repro.data import synthetic as syn
+        from repro.launch.mesh import make_aggregation_mesh
+        from repro.stream import service as S
+
+        m, d, r, iters, steps, nper = {m}, 48, 4, 10, 4, 128
+        tau = syn.spectrum_m1(d, r, delta=0.2)
+        _, _, factor = syn.covariance_from_spectrum(
+            jax.random.PRNGKey(0), tau)
+        rows = syn.sample_gaussian(
+            jax.random.PRNGKey(1), factor, steps * m * nper
+        ).reshape(steps, m, nper, d)
+        mesh = make_aggregation_mesh(m)
+
+        def served(budget):
+            S.SLICE_BUDGET_S = budget
+            svc = S.SubspaceService(mesh, d, r, cadence=1,
+                                    solver="subspace", iters=iters)
+            bases = []
+            for t in range(steps):
+                svc.observe(rows[t])
+                bases.append(np.asarray(svc.basis))
+            return svc, bases
+
+        whole, want = served(S.SLICE_BUDGET_S)
+        sliced, got = served(3.5 * S._iteration_s(d, r, "cpu"))
+        print("STEPS", whole._slice_steps, sliced._slice_steps)
+        print("SLICES", whole.stats["refresh_slices"],
+              sliced.stats["refresh_slices"], sliced.stats["refreshes"])
+        print("EQUAL", all(np.array_equal(a, b) for a, b in zip(want, got)))
+        """,
+        n_devices=m,
+    )
+    lines = dict(ln.split(" ", 1) for ln in out.strip().splitlines())
+    assert lines["STEPS"] == "(10,) (3, 3, 3, 1)"
+    assert lines["SLICES"] == "4 16 4"
+    assert lines["EQUAL"] == "True"
+
+
+def test_slice_rule_follows_the_shapes_and_the_chip():
+    """The slice rule at the benchmark's d=8192, r=128 on a v5e: 4
+    iterations a slice (2.13 ms modeled), 30 iterations in 8 slices.  At
+    the sizes the CPU tests run, and at ``launch.serve``'s d=256, r=8,
+    the whole solve fits one slice."""
+    from repro.stream.service import SLICE_BUDGET_S, _iteration_s, slice_steps
+
+    assert slice_steps(8192, 128, 30, "TPU v5 lite") == (4,) * 7 + (2,)
+    assert 4 * _iteration_s(8192, 128, "TPU v5 lite") <= SLICE_BUDGET_S
+    assert 5 * _iteration_s(8192, 128, "TPU v5 lite") > SLICE_BUDGET_S
+    for d, r in [(32, 4), (40, 3), (48, 4), (96, 4), (256, 8)]:
+        assert slice_steps(d, r, 30, "cpu") == (30,)
+    for iters in range(1, 40):
+        steps = slice_steps(8192, 128, iters, "tpu")
+        assert sum(steps) == iters and max(steps) <= 4
+
+
 def test_query_path_has_zero_collectives():
     """The serving claim: the steady-state query program is a replicated
     matmul — no collective primitive anywhere in its jaxpr."""

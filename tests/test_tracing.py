@@ -26,6 +26,7 @@ import pytest
 from repro.core.distributed import _mesh_program, distributed_pca
 from repro.data import synthetic as syn
 from repro.launch.mesh import make_aggregation_mesh
+from repro.stream import service as service_mod
 from repro.stream.service import SubspaceService
 
 D, R, CHUNK, CADENCE, STEPS = 32, 4, 64, 2, 5
@@ -79,6 +80,11 @@ def _spans(log_dir):
     return out
 
 
+# Slices of each refresh in the traced workload: a slice budget of 3.5
+# modeled iterations cuts its 10-iteration solve into 3, 3, 3, 1.
+SLICES = 4
+
+
 @pytest.fixture(scope="module")
 def run(tmp_path_factory):
     """The workload untraced, then again under the profiler."""
@@ -86,15 +92,18 @@ def run(tmp_path_factory):
     rows = _rows(256)
     chunks = _rows(STEPS * CHUNK, seed=1).reshape(STEPS, CHUNK, D)
     queries = _rows(QUERIES * 8, seed=2).reshape(QUERIES, 8, D)
-    plain = _workload(mesh, rows, chunks, queries)
-    # The traced run's first PCA call then builds its program again.
-    _mesh_program.cache_clear()
-    log_dir = str(tmp_path_factory.mktemp("trace"))
-    jax.profiler.start_trace(log_dir)
-    try:
-        traced = _workload(mesh, rows, chunks, queries)
-    finally:
-        jax.profiler.stop_trace()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(service_mod, "SLICE_BUDGET_S",
+                   3.5 * service_mod._iteration_s(D, R, "cpu"))
+        plain = _workload(mesh, rows, chunks, queries)
+        # The traced run's first PCA call then builds its program again.
+        _mesh_program.cache_clear()
+        log_dir = str(tmp_path_factory.mktemp("trace"))
+        jax.profiler.start_trace(log_dir)
+        try:
+            traced = _workload(mesh, rows, chunks, queries)
+        finally:
+            jax.profiler.stop_trace()
     return types.SimpleNamespace(plain=plain, traced=traced,
                                  spans=_spans(log_dir))
 
@@ -145,6 +154,8 @@ SERVICE_NESTING = [
     ("repro.service.refresh.wait_count", "repro.service.refresh", "refresh"),
     ("repro.service.refresh.dispatch", "repro.service.refresh", "refresh"),
     ("repro.service.refresh.wait_jump", "repro.service.refresh", "refresh"),
+    ("repro.service.refresh.slice", "repro.service.refresh", "refresh"),
+    ("repro.service.refresh.wait_slice", "repro.service.refresh", "refresh"),
 ]
 
 
@@ -168,6 +179,21 @@ def test_service_span_ids_count_steps_and_refreshes(run):
     jumps = _named(run.spans, "repro.service.refresh.wait_jump")
     assert [s.ids["refresh"] for s in jumps] == list(
         range(1, len(refreshes)))
+
+
+def test_each_refresh_runs_its_slices_in_order(run):
+    """Every refresh dispatches its slices 0..SLICES-1, and waits before
+    slice j (j >= 2) for slice j-2, so one slice at most is queued behind
+    the running one."""
+    refreshes = _named(run.spans, "repro.service.refresh")
+    slices = _named(run.spans, "repro.service.refresh.slice")
+    waits = _named(run.spans, "repro.service.refresh.wait_slice")
+    for f in refreshes:
+        n = f.ids["refresh"]
+        assert [s.ids["slice"] for s in slices
+                if s.ids["refresh"] == n] == list(range(SLICES))
+        assert [s.ids["slice"] for s in waits
+                if s.ids["refresh"] == n] == list(range(2, SLICES))
 
 
 def test_queries_and_drift_carry_their_ids(run):
